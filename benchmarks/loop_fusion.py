@@ -18,20 +18,16 @@ chunk — the acceptance bar is < 5% at the default cadence
 (experiments/bench_results.json).
 
 Timed via ``rl.runner.Trainer`` directly (warm call first, so compile time
-is excluded). The 4-fake-device mesh legs run in a subprocess because
-``--xla_force_host_platform_device_count`` must be set before jax init;
-there the scanned superstep routes through ``collect_and_add_sharded`` /
-``sharded_replay_sample``. Fake-device SPMD launches carry a large CONSTANT
-per-dispatch cost (~seconds of host-thread coordination, independent of scan
-length), so the mesh ratio is only meaningful with chunks long enough to
-amortize it — production ``eval_every`` chunks are 10k+ steps; real-ICI
-speedups are the roofline's story, these rows validate routing + overheads.
+is excluded). ``--mesh N`` adds the mesh legs (``execution.mesh_shards=N``,
+routed through ``collect_and_add_sharded`` / ``sharded_replay_sample``) in
+this same process, on the devices it already holds: the chips on an
+accelerator host, or fake CPU devices when the caller sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before starting it.
+Every row names the backend it ran on (``platform``); a CPU row is a CPU
+measurement, whatever its leg.
 
-  PYTHONPATH=src python -m benchmarks.loop_fusion
+  PYTHONPATH=src python -m benchmarks.loop_fusion [--mesh 4]
 """
-import os
-import subprocess
-import sys
 import time
 
 
@@ -131,45 +127,19 @@ def obs_overhead_steps_per_sec(steps: int, reps: int = 5) -> dict:
     return {tag: steps / b for tag, b in best.items()}
 
 
-_MESH_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-os.environ["JAX_PLATFORMS"] = "cpu"
-from benchmarks.loop_fusion import both_steps_per_sec
-for loop, sps in both_steps_per_sec(%d, mesh_shards=4, reps=3).items():
-    print(f"RESULT,{loop},{sps:.3f}")
-"""
-
-
-def _mesh_rows(steps):
-    env = dict(os.environ)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep + root
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    r = subprocess.run([sys.executable, "-c", _MESH_SCRIPT % steps],
-                       capture_output=True, text=True, env=env, timeout=900,
-                       cwd=root)
-    out = {}
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT,"):
-            _, loop, sps = line.split(",")
-            out[loop] = float(sps)
-    if not out:
-        raise RuntimeError(f"mesh subprocess failed: {r.stderr[-500:]}")
-    return out
-
-
-def run(scale: str = "quick"):
+def run(scale: str = "quick", mesh: int = 0):
+    import jax
     steps = {"smoke": 16, "quick": 64}.get(scale, 512)
     mesh_steps = 192 if scale == "quick" else 1024
+    platform = jax.default_backend()
     rows = []
 
     def emit(tag, sps, ratio=None):
         derived = f"{sps:.0f}_steps/s" + (f"_x{ratio:.1f}" if ratio else "")
         rows.append({"name": f"loop_fusion_{tag}", "us_per_call": 1e6 / sps,
-                     "derived": derived})
+                     "derived": derived, "platform": platform})
 
-    if scale == "smoke":      # CI bitrot guard: one rep, no subprocess legs
+    if scale == "smoke":      # CI bitrot guard: one rep, no mesh legs
         sps_py = steps_per_sec("python", steps, reps=1)
         sps_sc = steps_per_sec("scan", steps, reps=1)
         emit("python_1shard", sps_py)
@@ -187,12 +157,21 @@ def run(scale: str = "quick"):
     # ratio here = throughput retained with the stream on (1.00 = free)
     emit("obs_every50", obs["every50"], obs["every50"] / obs["off"])
     emit("obs_every1", obs["every1"], obs["every1"] / obs["off"])
-    mesh = _mesh_rows(mesh_steps)
-    emit("python_mesh4", mesh["python"])
-    emit("scan_mesh4", mesh["scan"], mesh["scan"] / mesh["python"])
+    if mesh:
+        sps = both_steps_per_sec(mesh_steps, mesh_shards=mesh, reps=3)
+        emit(f"python_mesh{mesh}", sps["python"])
+        emit(f"scan_mesh{mesh}", sps["scan"], sps["scan"] / sps["python"])
     return rows
 
 
 if __name__ == "__main__":
+    import argparse
+
     from benchmarks.common import print_rows
-    print_rows(run())
+    from repro.launch.compile_cache import enable_compile_cache
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--mesh", type=int, default=0,
+                    help="also time the mesh legs on this many devices")
+    args = ap.parse_args()
+    enable_compile_cache()
+    print_rows(run(mesh=args.mesh))

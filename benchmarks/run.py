@@ -24,8 +24,10 @@ import importlib
 import json
 import os
 import platform
-import time
+import sys
 from pathlib import Path
+
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def host_fingerprint() -> dict:
@@ -87,22 +89,23 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="one-rep kernel/loop benchmarks, failures fatal")
     args = ap.parse_args()
+    enable_compile_cache()
 
     mods = SMOKE_MODULES if args.smoke else MODULES
     if args.only:
         mods = [m for m in mods if args.only in m]
     scale = "smoke" if args.smoke else args.scale
-    all_rows = []
+    all_rows, failed = [], []
     print("name,us_per_call,derived")
     for mod_name in mods:
-        t0 = time.time()
         mod = importlib.import_module(mod_name)
         try:
             rows = mod.run(scale)
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # run the other modules, then exit nonzero
             if args.smoke:
                 raise
             print(f"{mod_name},0,ERROR:{type(e).__name__}:{e}")
+            failed.append(mod_name)
             continue
         for r in rows:
             print(f"{r['name']},{r['us_per_call']:.0f},{r['derived']}")
@@ -114,6 +117,10 @@ def main() -> None:
     out = Path("experiments/bench_smoke.json" if args.smoke
                else "experiments/bench_results.json")
     _merge_write(out, all_rows)
+    if failed:
+        print(f"benchmarks.run: {len(failed)} module(s) failed: "
+              f"{', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -57,6 +57,7 @@ signature) is gated by ``repro.check``:
 """
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl import Experiment, parse_overrides, presets
 
 
@@ -124,6 +125,7 @@ def main():
                          "bad segment with a perturbed key (crash-safe "
                          "rollback: python -m repro.guard.supervise)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.resume:
         if args.override or args.units is not None or args.guard:

@@ -20,6 +20,7 @@ per-variant diagnostics without perturbing the trained bits (see
 """
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl import Experiment, parse_overrides, presets
 
 VARIANTS = {
@@ -42,6 +43,7 @@ def main():
                     help="spec override, e.g. replay.backend=host or "
                          "n_step=3 (repeatable)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     overrides = parse_overrides(args.override)
     base = presets.get("rl-distributed").override(

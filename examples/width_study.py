@@ -11,6 +11,7 @@ battery per row would batch inside each fleet for free; try ``seeds=5``).
 """
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.rl import Sweep, parse_overrides, presets
 
 
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--override", action="append", default=[],
                     metavar="KEY=VALUE")
     args = ap.parse_args()
+    enable_compile_cache()
     base = presets.get("fig4-grid").override(
         n_env=1, total_steps=args.steps, warmup_steps=300,
         eval_every=max(args.steps // 2, 1),

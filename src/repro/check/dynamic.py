@@ -153,6 +153,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--steps", type=int, default=None,
                     help="override the per-phase step budget")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     findings = run_sanitizers(args.preset, steps=args.steps)
     print(render(findings))
     return 1 if findings else 0

@@ -124,14 +124,6 @@ def huber(x: jax.Array, delta: float = 1.0) -> jax.Array:
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking off.
-
-    ``jax.shard_map(check_vma=...)`` landed after the pinned jax; fall back
-    to ``jax.experimental.shard_map.shard_map(check_rep=False)`` there.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication (vma) checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

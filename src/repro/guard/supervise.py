@@ -144,10 +144,13 @@ def _parse(argv) -> argparse.Namespace:
 
 def _worker(args) -> int:
     # heavy imports only in the worker: the parent stays a thin respawner
+    # that never initializes a JAX backend (it must not hold the chip)
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.rl import presets
     from repro.rl.experiment import Experiment, parse_overrides
     from repro.rl.sweep import Fleet
 
+    enable_compile_cache()
     run_dir = Path(args.dir)
     spec = presets.get(args.preset)
     if args.override:

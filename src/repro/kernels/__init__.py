@@ -1,15 +1,22 @@
-"""Pallas TPU kernels for the compute hot spots (validated on CPU via
-interpret=True): the paper's wide-DenseNet dense layer (fused
-concat-matmul-swish) and the fused multi-layer DenseNet *stack*
-(dense_block/stack.py — forward + custom-VJP backward, the first kernel the
-RL agents train through), flash attention for the transformer substrate's
-prefill path, the Mamba2 SSD intra-chunk dual form, and the replay
-sum-tree (fused proportional-descent sample + one-hot-matmul set) backing
-the device-resident prioritized replay in repro.replay.
+"""Pallas TPU kernels, validated on CPU in interpret mode.
 
-``default_interpret()`` is the shared interpret-mode policy: kernels
-real-lower on TPU and fall back to the Pallas interpreter everywhere else,
-so the same call sites work unchanged on CPU CI and TPU hardware.
+On the RL training path (what ``repro.rl`` runs):
+
+* ``dense_block/stack.py`` — the fused L-layer MLP/DenseNet/D2RL stack,
+  forward + custom-VJP backward; SAC/TD3/OFENet train through it under
+  ``network.block_backend="fused"``.
+* ``replay_tree/`` — the device sum-tree (fused proportional-descent
+  sample + one-hot set) behind prioritized device replay under
+  ``replay.kernel="pallas"``.
+
+Not on the RL path: the single-layer ``dense_block/dense_block.py`` /
+``ops.py`` kernels, ``flash_attention/`` and ``ssd_scan/`` (used only by
+their tests and ``benchmarks/kernels_micro.py``).
+
+``default_interpret()`` is the one interpret-mode policy: kernels lower
+through Mosaic on TPU and run in the Pallas interpreter everywhere else.
+A caller that pins ``interpret=False`` off-TPU gets an error from
+``require_mosaic``, never a silent substitute.
 """
 from __future__ import annotations
 
@@ -28,3 +35,12 @@ def default_interpret(interpret: Optional[bool] = None) -> bool:
     if interpret is None:
         return not mosaic_available()
     return bool(interpret)
+
+
+def require_mosaic(what: str) -> None:
+    """Raise unless ``what`` can lower through Mosaic here."""
+    if not mosaic_available():
+        raise RuntimeError(
+            f"{what}: interpret=False needs a TPU backend (Mosaic), but "
+            f"jax.default_backend() is {jax.default_backend()!r}; pass "
+            f"interpret=True or leave it to default_interpret()")

@@ -33,6 +33,8 @@ Supported / fallback matrix (``mlp_block_apply``, see MLPBlockConfig):
     jnp     everything else: resnet (skip-add), batch_norm=True (running
             stats + cross-replica psum), gelu, num_layers == 0
 
-The fallback is silent and exact — flipping ``backend="fused"`` is always
-safe; unsupported configs just keep the reference loop.
+The fallback is silent and exact: unsupported configs keep the reference
+loop. On TPU the fused kernel keeps weights in VMEM, so past the widths
+listed in ``stack.py`` (e.g. a densenet SAC trunk wider than U=512) the
+training program fails to compile instead (ROADMAP R2).
 """

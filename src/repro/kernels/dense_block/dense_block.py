@@ -17,14 +17,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import default_interpret
-
-try:  # TPU memory spaces; interpret mode emulates them on CPU
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda bm, bn: pltpu.VMEM((bm, bn), jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda bm, bn: pl.MemorySpace.ANY
 
 _ACTS = {
     "identity": lambda x: x,
@@ -84,6 +79,6 @@ def fused_dense(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        scratch_shapes=[_SCRATCH(bm, bn)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, w, b.reshape(1, n))

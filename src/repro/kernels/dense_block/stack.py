@@ -43,10 +43,16 @@ Supported: connectivity in {densenet, d2rl, mlp}, activation in
 setting. ``core.blocks.mlp_block_apply(backend="fused")`` routes here and
 falls back to the jnp loop for everything else (BN, resnet, gelu).
 
-VMEM note: weights + dW accumulators stay resident across batch tiles, so
-the kernel budget is ~2x the stacked weight bytes; fine through the paper's
-L=8/U=256 nets, while L>=8 at U>=512 needs the K-tiled layer streaming
-listed as a ROADMAP follow-on (the XLA path has no such limit).
+VMEM note: weights + dW accumulators stay resident across batch tiles, and
+XLA may place small kernel operands in the kernel's 16 MiB of scoped VMEM.
+Forward + backward compile for TPU v5e (batch 256, input width 256;
+tests/test_tpu_compile.py) up to
+    densenet  L=2 U=1024 | L=4 U=512  | L=8 U=128
+    d2rl      L=2 U=2048 | L=8 U=1024
+    mlp       L=2 U=1024 | L=4 U=512  | L=8 U=256
+and inside the SAC training chunk (inputs up to 516 wide) the L=2 densenet
+trunk only up to U=512. Wider needs the K-tiled layer streaming of ROADMAP
+R2; the XLA path has no such limit.
 """
 from __future__ import annotations
 
@@ -57,14 +63,9 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import default_interpret
-
-try:  # TPU memory spaces; interpret mode emulates them on CPU
-    from jax.experimental.pallas import tpu as pltpu
-    _SCRATCH = lambda shape: pltpu.VMEM(shape, jnp.float32)
-except Exception:  # pragma: no cover
-    _SCRATCH = lambda shape: pl.MemorySpace.ANY
+from repro.kernels import default_interpret, mosaic_available
 
 FUSED_CONNECTIVITIES = ("mlp", "densenet", "d2rl")
 FUSED_ACTIVATIONS = ("swish", "silu", "relu", "tanh", "identity")
@@ -417,7 +418,7 @@ def _pallas_forward(plan: _StackPlan, x, ws, bs):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, plan.feat_w), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, plan.feat_w), x.dtype),
-        scratch_shapes=[_SCRATCH((bm, plan.acc_w))],
+        scratch_shapes=[pltpu.VMEM((bm, plan.acc_w), jnp.float32)],
         interpret=plan.interpret,
     )(x, *ws, *bs)
 
@@ -449,9 +450,9 @@ def _pallas_backward(plan: _StackPlan, x, g, ws, bs):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[_SCRATCH((bm, plan.acc_w)),
-                        _SCRATCH((bm, L * plan.up)),
-                        _SCRATCH((bm, plan.acc_w))],
+        scratch_shapes=[pltpu.VMEM((bm, plan.acc_w), jnp.float32),
+                        pltpu.VMEM((bm, L * plan.up), jnp.float32),
+                        pltpu.VMEM((bm, plan.acc_w), jnp.float32)],
         interpret=plan.interpret,
     )(x, g, *ws, *bs)
     return outs[0], outs[1:L + 1], outs[L + 1:]
@@ -585,7 +586,7 @@ def dense_stack(x: jax.Array, ws: Sequence[jax.Array],
     if not ws:
         raise ValueError("dense_stack needs at least one layer")
     if impl is None:
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
+        impl = "pallas" if mosaic_available() else "xla"
     if impl not in ("xla", "pallas"):
         raise ValueError(impl)
     plan = _StackPlan(connectivity, activation, len(ws), x.shape[-1],

@@ -16,16 +16,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    def _scratch(bq, d):
-        return [pltpu.VMEM((bq,), jnp.float32),
-                pltpu.VMEM((bq,), jnp.float32),
-                pltpu.VMEM((bq, d), jnp.float32)]
-except Exception:  # pragma: no cover
-    def _scratch(bq, d):
-        return [pl.MemorySpace.ANY] * 3
+
+def _scratch(bq, d):
+    return [pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32)]
 
 NEG_INF = -1e30
 

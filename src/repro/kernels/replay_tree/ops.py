@@ -5,7 +5,8 @@
 jnp oracle from ``ref.py`` — the same functions the tests use as ground
 truth, and the sensible default on CPU where interpret-mode Pallas is slow.
 ``repro.replay`` calls only through this layer, so the replay subsystem is
-backend-agnostic.
+backend-agnostic. Asking for ``backend="pallas", interpret=False`` where
+Mosaic cannot lower (off-TPU) raises instead of running something else.
 """
 from __future__ import annotations
 
@@ -15,7 +16,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import mosaic_available
+from repro.kernels import require_mosaic
 from repro.kernels.replay_tree import ref
 from repro.kernels.replay_tree.replay_tree import (tree_sample, tree_set,
                                                    tree_set_onehot)
@@ -44,19 +45,14 @@ def sumtree_set(tree: jax.Array, idx: jax.Array, value: jax.Array, *,
     ``backend="pallas"`` under interpret mode runs the scatter+resum kernel
     (scatter does not lower on Mosaic); real-lowering on TPU routes to
     ``tree_set_onehot``, which rewrites the scatter as per-level one-hot
-    matmul delta propagation — so on hardware both the sample descent AND
-    the priority refresh stay fused Pallas kernels. Off-TPU with
-    ``interpret=False`` there is no Mosaic to lower against, so this falls
-    back to the XLA scatter ref rather than failing to compile. (CI runs
-    the one-hot kernel in interpret mode only; its hardware lowering is
-    pending a TPU smoke job — see ROADMAP.)
+    delta propagation — so on hardware both the sample descent AND the
+    priority refresh stay fused Pallas kernels.
     """
     assert backend in BACKENDS, backend
-    if backend == "pallas" and not interpret and not mosaic_available():
-        backend = "xla"
     if backend == "pallas":
         if interpret:
             return tree_set(tree, idx, value, interpret=True)
+        require_mosaic("sumtree_set")
         return tree_set_onehot(tree, idx, value, interpret=False)
     return ref.tree_set_ref(tree, idx, value)
 
@@ -69,16 +65,13 @@ def sumtree_sample(tree: jax.Array, targets: jax.Array, *, capacity: int,
     """Batch proportional descent -> (leaf_idx, leaf_priority).
 
     Targets are padded up to a multiple of the kernel's batch tile ``bt``;
-    the pad lanes descend with target 0 and are sliced off. As with
-    ``sumtree_set``, ``interpret=False`` off-TPU falls back to the jnp ref
-    (real lowering needs Mosaic) so the pallas backend stays runnable
-    end-to-end on CPU hosts.
+    the pad lanes descend with target 0 and are sliced off.
     """
     assert backend in BACKENDS, backend
     (b,) = targets.shape
-    if backend == "pallas" and not interpret and not mosaic_available():
-        backend = "xla"
     if backend == "pallas":
+        if not interpret:
+            require_mosaic("sumtree_sample")
         pad = (-b) % bt
         tp = jnp.pad(targets, (0, pad)) if pad else targets
         leaf, pri = tree_sample(tree, tp, capacity=capacity, bt=bt,

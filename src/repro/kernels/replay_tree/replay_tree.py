@@ -2,11 +2,14 @@
 
 The Ape-X hot loop samples a batch of leaves by proportional descent every
 learner step.  ``tree_sample`` fuses the whole descent into one kernel: the
-tree lives in a VMEM-resident block, the batch of target masses is gridded
-into ``bt``-wide tiles, and each program unrolls the ``depth - 1`` levels of
-``gather -> compare -> subtract`` without ever writing intermediate node
-indices to HBM.  Leaf index AND leaf priority come back in the same pass, so
-the importance-weight computation needs no second gather round-trip.
+tree lives in a VMEM-resident ``(rows, 128)`` block, the batch of target
+masses is gridded into ``bt``-wide tiles, and each program unrolls the
+``depth - 1`` levels of ``lookup -> compare -> subtract`` without ever
+writing intermediate node indices to HBM.  Mosaic lowers no 1-D gather, so
+a lookup picks the node's row (a one-hot matmul on wide levels, VPU selects
+on narrow ones) and then its lane (a masked lane sum).  Leaf index AND leaf
+priority come back in the same pass, so the importance-weight computation
+needs no second gather round-trip.
 
 ``tree_set`` is the write side: scatter a batch of leaf priorities and
 recompute the ancestor partial sums bottom-up, aliasing the tree in/out so
@@ -14,15 +17,15 @@ the update is in-place.  Scatter does not lower on Mosaic, so ``tree_set``
 stays the interpret-mode/CPU reference; ``tree_set_onehot`` is the
 TPU-lowerable twin that expresses the same update scatter-free: write a
 batch of leaf *deltas* (new - old, duplicate indices masked keep-last) and
-propagate each delta to its ancestor at every level with a one-hot matmul
-``delta @ (node_id == iota)`` — wide levels are walked in lane-aligned
-chunks via ``fori_loop`` + dynamic stores.  ``ops.sumtree_set`` routes
-``backend="pallas"`` to the scatter kernel under interpret mode and to the
-one-hot kernel when real-lowering, so sampling AND priority refresh are both
-fused on hardware.
+add each delta to its ancestor at every level, as a one-hot matmul
+``(row one-hot * delta)^T @ lane one-hot`` over row blocks on wide levels.
+``ops.sumtree_set`` routes ``backend="pallas"`` to the scatter kernel under
+interpret mode and to the one-hot kernel when real-lowering, so sampling
+AND priority refresh are both fused on hardware.
 
 All kernels are validated in interpret mode against ``ref.py`` in
-tests/test_kernels.py, following the dense_block/ssd_scan layout.
+tests/test_kernels.py; tests/test_tpu_compile.py compiles the two
+TPU-lowered ones for a v5e.
 """
 from __future__ import annotations
 
@@ -33,22 +36,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+_LANES = 128            # the tree is laid out (rows, 128) in VMEM
+_MXU_ROWS = 128         # levels this many rows wide use the one-hot matmul
+_CHUNK = 1 << 17        # nodes one one-hot matmul spans (512 KiB of f32)
+
+
+def _as_rows(tree: jax.Array) -> jax.Array:
+    """Flat ``(2**depth,)`` tree -> lane-dense ``(rows, 128)`` (zero-padded
+    below 128 nodes; padding nodes are never addressed)."""
+    size = tree.shape[0]
+    if size < _LANES:
+        tree = jnp.pad(tree, (0, _LANES - size))
+    return tree.reshape(-1, _LANES)
+
+
+def _level_rows(level: int) -> tuple[int, int]:
+    """Row range ``[lo, hi)`` holding the nodes of tree level ``level``
+    (absolute ids ``[2**level, 2**(level+1))``)."""
+    s = 1 << level
+    if s < _LANES:
+        return 0, 1
+    return s // _LANES, 2 * s // _LANES
+
+
+def _lookup(tree_ref, node, level: int, block: int):
+    """``tree[node]`` for an ``(n, 1)`` column of node ids on one level.
+
+    Gather-free, so it lowers on Mosaic: wide levels select each node's
+    row with a one-hot matmul at fp32 precision (exact: one-hot weights
+    are 0/1), narrow ones with a VPU select per row; the lane is then
+    picked with a masked lane reduction.
+    """
+    n = node.shape[0]
+    row, lane = node // _LANES, node % _LANES
+    lo, hi = _level_rows(level)
+    if hi - lo >= _MXU_ROWS:
+        vals = jnp.zeros((n, _LANES), jnp.float32)
+        for r0 in range(lo, hi, block):
+            r1 = min(r0 + block, hi)
+            oh = (row == r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (n, r1 - r0), 1)).astype(jnp.float32)
+            vals += jnp.dot(oh, tree_ref[r0:r1, :],
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    else:
+        vals = jnp.broadcast_to(tree_ref[lo:lo + 1, :], (n, _LANES))
+        for r in range(lo + 1, hi):
+            vals = jnp.where(row == r, tree_ref[r:r + 1, :], vals)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (n, _LANES), 1)
+    return jnp.sum(jnp.where(lanes == lane, vals, 0.0), axis=1,
+                   keepdims=True)
+
+
 def _sample_kernel(tree_ref, t_ref, leaf_ref, pri_ref, *, depth: int,
-                   capacity: int):
-    tree = tree_ref[0, :]
-    half = tree.shape[0] // 2
-    t = t_ref[0, :].astype(jnp.float32)
+                   capacity: int, block: int):
+    half = 1 << (depth - 1)
+    t = t_ref[...].astype(jnp.float32)
     node = jnp.ones(t.shape, jnp.int32)
-    for _ in range(depth - 1):          # static unroll: root -> leaf level
+    for lvl in range(1, depth):         # static unroll: root -> leaf level
         left = 2 * node
-        lmass = jnp.take(tree, left)
+        lmass = _lookup(tree_ref, left, lvl, block)
         go_right = t >= lmass
         t = jnp.where(go_right, t - lmass, t)
         node = jnp.where(go_right, left + 1, left)
     # clamp into the valid leaf range (zero-priority padding tail)
     leaf = jnp.clip(node - half, 0, capacity - 1)
-    leaf_ref[0, :] = leaf
-    pri_ref[0, :] = jnp.take(tree, leaf + half)
+    leaf_ref[...] = leaf
+    pri_ref[...] = _lookup(tree_ref, leaf + half, depth - 1, block)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "bt", "interpret"))
@@ -60,28 +114,24 @@ def tree_sample(tree: jax.Array, targets: jax.Array, *, capacity: int,
     tree: (2**depth,) float32; targets: (B,) with B a multiple of ``bt``
     (ops.py pads).  Returns (leaf_idx int32, leaf_priority f32), both (B,).
     """
-    size = tree.shape[0]
-    depth = size.bit_length() - 1
+    depth = tree.shape[0].bit_length() - 1
     (b,) = targets.shape
     assert b % bt == 0, (b, bt)
+    rows = _as_rows(tree)
+    col = pl.BlockSpec((bt, 1), lambda i: (i, 0))
     leaf, pri = pl.pallas_call(
-        functools.partial(_sample_kernel, depth=depth, capacity=capacity),
+        functools.partial(_sample_kernel, depth=depth, capacity=capacity,
+                          block=_CHUNK // _LANES),
         grid=(b // bt,),
-        in_specs=[
-            pl.BlockSpec((1, size), lambda i: (0, 0)),
-            pl.BlockSpec((1, bt), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bt), lambda i: (0, i)),
-            pl.BlockSpec((1, bt), lambda i: (0, i)),
-        ],
+        in_specs=[pl.BlockSpec(rows.shape, lambda i: (0, 0)), col],
+        out_specs=[col, col],
         out_shape=[
-            jax.ShapeDtypeStruct((1, b), jnp.int32),
-            jax.ShapeDtypeStruct((1, b), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(tree.reshape(1, size), targets.reshape(1, b))
-    return leaf[0], pri[0]
+    )(rows, targets.reshape(b, 1))
+    return leaf[:, 0], pri[:, 0]
 
 
 def _set_kernel(tree_ref, idx_ref, val_ref, out_ref, *, depth: int):
@@ -124,70 +174,72 @@ def tree_set(tree: jax.Array, idx: jax.Array, value: jax.Array, *,
       value.reshape(1, n))[0]
 
 
-def _set_onehot_kernel(tree_ref, idx_ref, val_ref, out_ref, *, depth: int,
-                       chunk: int):
-    size = 1 << depth
-    half = size // 2
-    tree = tree_ref[0, :]
-    out_ref[0, :] = tree
-    idx = idx_ref[0, :]
+def _set_onehot_kernel(tree_ref, idx_ref, idx_row_ref, val_ref, out_ref,
+                       *, depth: int, block: int):
+    half = 1 << (depth - 1)
+    out_ref[...] = tree_ref[...]
+    idx = idx_ref[...]                                  # (n, 1)
     n = idx.shape[0]
     leaf = idx + half
-    old = jnp.take(tree, leaf)
+    old = _lookup(tree_ref, leaf, depth - 1, block)
     # keep-LAST duplicate semantics (the host SumTree's): mask every write
     # that has a later duplicate, then deltas of distinct leaves sum freely
     ii = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    later_dup = (idx[:, None] == idx[None, :]) & (jj > ii)
-    keep = jnp.logical_not(jnp.any(later_dup, axis=1))
-    delta = ((val_ref[0, :].astype(jnp.float32) - old)
-             * keep.astype(jnp.float32)).reshape(1, n)
-    for lvl in range(depth - 1, -1, -1):       # leaves -> root
-        s = 1 << lvl
-        rel = (leaf >> (depth - 1 - lvl)) - s  # node ids within the level
-        if s <= chunk:
-            oh = (rel[:, None] ==
-                  jax.lax.broadcasted_iota(jnp.int32, (n, s), 1))
-            out_ref[0, s:2 * s] += jax.lax.dot_general(
-                delta, oh.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)[0]
-        else:                                  # wide level: chunked columns
-            def body(c, _):
-                col0 = c * chunk
-                oh = (rel[:, None] == col0 + jax.lax.broadcasted_iota(
-                    jnp.int32, (n, chunk), 1))
-                out_ref[0, pl.ds(s + col0, chunk)] += jax.lax.dot_general(
-                    delta, oh.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)[0]
-                return 0
-            jax.lax.fori_loop(0, s // chunk, body, 0)
+    later_dup = (idx == idx_row_ref[...]) & (jj > ii)
+    keep = jnp.logical_not(jnp.any(later_dup, axis=1, keepdims=True))
+    delta = (val_ref[...].astype(jnp.float32) - old) * keep.astype(
+        jnp.float32)                                    # (n, 1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (n, _LANES), 1)
+    for lvl in range(depth - 1, -1, -1):                # leaves -> root
+        anc = leaf >> (depth - 1 - lvl)                 # ancestor node ids
+        row = anc // _LANES
+        at_lane = lanes == anc % _LANES
+        lo, hi = _level_rows(lvl)
+        if hi - lo >= _MXU_ROWS:
+            # (rows, 128) increments = (row one-hot * delta)^T @ lane one-hot
+            for r0 in range(lo, hi, block):
+                r1 = min(r0 + block, hi)
+                a = jnp.where(row == r0 + jax.lax.broadcasted_iota(
+                    jnp.int32, (n, r1 - r0), 1), delta, 0.0)
+                out_ref[r0:r1, :] += jax.lax.dot_general(
+                    a, at_lane.astype(jnp.float32), (((0,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+        else:
+            for r in range(lo, hi):
+                out_ref[r:r + 1, :] += jnp.sum(
+                    jnp.where(at_lane & (row == r), delta, 0.0), axis=0,
+                    keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "chunk"))
 def tree_set_onehot(tree: jax.Array, idx: jax.Array, value: jax.Array, *,
-                    interpret: bool = True, chunk: int = 1024) -> jax.Array:
-    """Scatter-free ``tree_set``: per-level one-hot matmul delta propagation.
+                    interpret: bool = True, chunk: int = _CHUNK) -> jax.Array:
+    """Scatter-free ``tree_set``: per-level one-hot delta propagation.
 
     Mathematically identical to ``tree_set``/``ref.tree_set_ref`` with
     keep-last duplicate resolution; lowers on Mosaic because the only data
-    movement is dense matmuls and (dynamic-)sliced adds. ``chunk`` bounds
-    the one-hot tile width for wide levels (must be a power of two).
+    movement is dense matmuls, masked reductions and static slices of the
+    ``(rows, 128)`` tree. ``chunk`` bounds the nodes one one-hot matmul
+    spans on wide levels (must be a power of two).
     """
     size = tree.shape[0]
     depth = size.bit_length() - 1
     (n,) = idx.shape
     assert chunk & (chunk - 1) == 0, chunk
-    return pl.pallas_call(
-        functools.partial(_set_onehot_kernel, depth=depth, chunk=chunk),
+    rows = _as_rows(tree)
+    idx = idx.astype(jnp.int32)
+    full = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
+    out = pl.pallas_call(
+        functools.partial(_set_onehot_kernel, depth=depth,
+                          block=max(chunk // _LANES, 1)),
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec((1, size), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, size), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, size), tree.dtype),
+        in_specs=[full(rows.shape), full((n, 1)), full((1, n)),
+                  full((n, 1))],
+        out_specs=full(rows.shape),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, tree.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(tree.reshape(1, size), idx.reshape(1, n).astype(jnp.int32),
-      value.reshape(1, n))[0]
+    )(rows, idx.reshape(n, 1), idx.reshape(1, n), value.reshape(n, 1))
+    return out.reshape(-1)[:size]

@@ -1,43 +1,33 @@
-"""Production mesh construction (deliverable e).
+"""Device meshes for the sharded RL path.
 
-``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
-importing this module never touches jax device state; the dry-run launcher
-sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import, everything else sees the real single CPU device.
-
-Hardware model (TPU v5e class, used by roofline/):
-    197 TFLOP/s bf16 per chip | 819 GB/s HBM | ~50 GB/s/link ICI.
+``make_actor_mesh`` builds the one-axis ``data`` mesh that
+``execution.mesh_shards`` trains on; ``make_debug_mesh`` a small
+``(data, model)`` mesh for tests. Both are functions, so importing this
+module never touches device state. Both declare their axes
+``AxisType.Auto``: the sharded superstep is written for compiler-propagated
+shardings (``shard_map`` bodies plus ``with_sharding_constraint``), and
+``jax.make_mesh`` would otherwise default to ``Explicit`` axes, under which
+ops such as ``jnp.median`` of a sharded vector refuse to trace.
 """
 from __future__ import annotations
 
 import jax
-
-PEAK_FLOPS = 197e12           # bf16 per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
+from jax.sharding import AxisType
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_debug_mesh(n_data: int = 2, n_model: int = 2, *,
-                    multi_pod: bool = False):
+def make_debug_mesh(n_data: int = 2, n_model: int = 2):
     """Small mesh for in-process tests (requires >= n_data*n_model devices)."""
-    if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_actor_mesh(n_data: int):
     """Data-only mesh for the RL runner's sharded actor/replay path
     (``ExperimentSpec`` ``execution.mesh_shards=n``): one ``data`` slice
-    per replay
-    shard / actor-pool slice, no model axis. Works on real devices or a
-    ``--xla_force_host_platform_device_count`` fake CPU mesh."""
-    return jax.make_mesh((int(n_data),), ("data",))
+    per replay shard / actor-pool slice, no model axis. Works on real
+    devices or a ``--xla_force_host_platform_device_count`` fake CPU mesh."""
+    return jax.make_mesh((int(n_data),), ("data",),
+                         axis_types=(AxisType.Auto,))
 
 
 def replay_shards(mesh) -> int:

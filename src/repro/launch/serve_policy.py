@@ -60,6 +60,8 @@ import numpy as np
 
 import jax
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 class ServerClosed(RuntimeError):
     """Submission after ``close()`` — the server no longer accepts work."""
@@ -387,6 +389,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("--max-batch", type=int, default=32)
     p.add_argument("--max-wait-ms", type=float, default=2.0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     from repro.guard import DurableStore
     from repro.rl import presets

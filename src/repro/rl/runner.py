@@ -65,7 +65,7 @@ staleness keys are omitted there.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable, Dict, List, NamedTuple
 
 import jax
@@ -75,6 +75,7 @@ from jax.experimental import io_callback
 
 from repro.common import tree_size
 from repro.core.effective_rank import effective_rank
+from repro.kernels import default_interpret
 from repro.obs.trace import annotate
 from repro.launch.mesh import make_actor_mesh, replay_shards
 from repro.replay import (DeviceReplayConfig, nstep_emit_flat, nstep_init,
@@ -229,7 +230,7 @@ class Trainer:
                 capacity=r.capacity // shards, obs_dim=env.obs_dim,
                 act_dim=env.act_dim, uniform=not r.prioritized,
                 backend=r.kernel,
-                interpret=jax.default_backend() == "cpu",
+                interpret=default_interpret(),
                 n_step=r.n_step)
             self.buffer = None
         else:
@@ -266,6 +267,9 @@ class Trainer:
             else self.policy0.with_params(params)
 
     def _count(self, fn):
+        # functools.wraps keeps the jitted fn reachable (``__wrapped__``)
+        # for AOT lowering, e.g. to inspect a chunk's compiled HLO
+        @wraps(fn)
         def wrapped(*args, **kwargs):
             self.dispatches += 1
             return fn(*args, **kwargs)

@@ -271,6 +271,8 @@ def test_supervisor_sigkill_resume_is_bitwise(tmp_path, monkeypatch):
     monkeypatch.setenv(
         "PYTHONPATH",
         src + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    # the worker is a CLI entry point and would cache compiles; tests don't
+    monkeypatch.setenv("JAX_ENABLE_COMPILATION_CACHE", "false")
 
     killed = tmp_path / "killed"
     rc = supervise.main([
@@ -300,6 +302,8 @@ def test_supervisor_budget_spent_writes_incident(tmp_path, monkeypatch):
     monkeypatch.setenv(
         "PYTHONPATH",
         src + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    # the worker is a CLI entry point and would cache compiles; tests don't
+    monkeypatch.setenv("JAX_ENABLE_COMPILATION_CACHE", "false")
 
     run = tmp_path / "halted"
     rc = supervise.main([
@@ -313,3 +317,42 @@ def test_supervisor_budget_spent_writes_incident(tmp_path, monkeypatch):
     att = inc["attempts"][0]
     assert att["exit_code"] == supervise.EXIT_GUARD
     assert any(v["reason"] == "nonfinite_params" for v in att["violations"])
+
+
+_PARENT_ONLY = r"""
+import sys
+import jax._src.xla_bridge as xb
+from repro.guard import supervise
+
+spawned = []
+
+
+class _Done:
+    returncode = 0
+
+
+def _fake_run(argv, *a, **k):
+    spawned.append(argv)
+    return _Done()
+
+
+supervise.subprocess.run = _fake_run
+rc = supervise.main(["smoke", "--dir", sys.argv[1], "--retries", "0"])
+assert rc == 0 and spawned and spawned[0][-1] == "--worker", spawned
+assert not xb.backends_are_initialized(), "supervisor parent touched JAX"
+print("OK")
+"""
+
+
+def test_supervisor_parent_never_initializes_a_backend(tmp_path):
+    """The parent only respawns workers. On an accelerator host a parent
+    that initialized a backend would hold the chip its worker needs, so
+    it must never do so (fresh interpreter, worker spawn stubbed out)."""
+    import subprocess
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (str(Path(__file__).resolve().parent.parent / "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    r = subprocess.run([sys.executable, "-c", _PARENT_ONLY, str(tmp_path)],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert "OK" in r.stdout

@@ -184,8 +184,11 @@ def test_replay_tree_set_kernel_matches_ref(capacity):
                                rtol=1e-6)
 
 
-@pytest.mark.parametrize("capacity,bt", [(37, 16), (128, 64), (1000, 128)])
+@pytest.mark.parametrize("capacity,bt", [(37, 16), (128, 64), (1000, 128),
+                                         (20000, 128)])
 def test_replay_tree_sample_kernel_matches_ref(capacity, bt):
+    """Capacity 20000 puts the leaf level in 256 rows of 128 nodes: the
+    one-hot-matmul row lookup path, not just the VPU row selects."""
     rng = np.random.default_rng(11)
     pr = jnp.asarray(rng.uniform(0.0, 3.0, capacity), jnp.float32)
     tree = rt_ref.tree_set_ref(rt_ref.tree_init_ref(capacity),
@@ -201,13 +204,19 @@ def test_replay_tree_sample_kernel_matches_ref(capacity, bt):
 
 
 @pytest.mark.parametrize("capacity,chunk", [(5, 1024), (37, 1024), (64, 16),
-                                            (200, 1024), (3000, 1024)])
+                                            (200, 1024), (3000, 1024),
+                                            (20000, 1 << 14)])
 def test_replay_tree_set_onehot_matches_ref(capacity, chunk):
-    """The TPU-lowerable scatter-free tree_set == jnp oracle; capacity 3000
-    (tree size 8192) and chunk 16 exercise the chunked wide-level loop."""
+    """The TPU-lowerable scatter-free tree_set == jnp oracle; capacity 20000
+    (leaf level 256 rows of 128) with chunk 2**14 (128 rows per one-hot
+    matmul) exercises the blocked matmul path on wide levels."""
     rng = np.random.default_rng(13)
-    pr = jnp.asarray(rng.uniform(0.1, 5.0, capacity), jnp.float32)
-    idx = jnp.arange(capacity)
+    # every leaf of a small tree; 2048 distinct leaves of a wide one (the
+    # kernel's duplicate mask is (n, n))
+    idx = (np.arange(capacity) if capacity <= 4096
+           else rng.choice(capacity, 2048, replace=False))
+    pr = jnp.asarray(rng.uniform(0.1, 5.0, idx.size), jnp.float32)
+    idx = jnp.asarray(idx)
     t_k = tree_set_onehot(rt_ref.tree_init_ref(capacity), idx, pr,
                           chunk=chunk)
     t_r = rt_ref.tree_set_ref(rt_ref.tree_init_ref(capacity), idx, pr)
@@ -260,29 +269,23 @@ def test_replay_tree_ops_match_host_sumtree(backend):
 
 
 def test_replay_tree_pallas_interpret_off_runs_off_tpu():
-    """backend='pallas', interpret=False off-TPU must fall back to the jnp
-    ref (Mosaic-only lowering) for BOTH the set and sample sites, so a
-    DeviceReplayConfig pinned to real lowering stays runnable on CPU."""
+    """backend='pallas', interpret=False off-TPU raises at BOTH the set and
+    sample sites: real lowering needs Mosaic, and quietly running the XLA
+    reference instead would hide that the kernel never ran."""
     if jax.default_backend() == "tpu":
-        pytest.skip("off-TPU fallback path")
+        pytest.skip("off-TPU path")
     capacity = 41
-    rng = np.random.default_rng(14)
-    pr = jnp.asarray(rng.uniform(0.1, 3.0, capacity), jnp.float32)
     tree = rt_ops.sumtree_set(rt_ops.sumtree_init(capacity),
-                              jnp.arange(capacity), pr,
-                              backend="pallas", interpret=False)
-    ref_tree = rt_ref.tree_set_ref(rt_ref.tree_init_ref(capacity),
-                                   jnp.arange(capacity), pr)
-    np.testing.assert_allclose(np.asarray(tree), np.asarray(ref_tree),
-                               rtol=1e-6)
-    targets = jnp.asarray(
-        rng.uniform(0, float(rt_ops.sumtree_total(tree)), 64), jnp.float32)
-    leaf, pri = rt_ops.sumtree_sample(tree, targets, capacity=capacity,
-                                      backend="pallas", interpret=False)
-    leaf_r = rt_ref.tree_sample_ref(ref_tree, targets, capacity=capacity)
-    np.testing.assert_array_equal(np.asarray(leaf), np.asarray(leaf_r))
-    np.testing.assert_allclose(np.asarray(pri),
-                               np.asarray(pr)[np.asarray(leaf)], rtol=1e-6)
+                              jnp.arange(capacity),
+                              jnp.ones((capacity,), jnp.float32))
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        rt_ops.sumtree_set(tree, jnp.arange(capacity),
+                           jnp.ones((capacity,), jnp.float32),
+                           backend="pallas", interpret=False)
+    with pytest.raises(RuntimeError, match="needs a TPU backend"):
+        rt_ops.sumtree_sample(tree, jnp.zeros((64,), jnp.float32),
+                              capacity=capacity, backend="pallas",
+                              interpret=False)
 
 
 @pytest.mark.parametrize("backend", ["xla", "pallas"])

@@ -1,0 +1,94 @@
+"""Compile the RL path's Pallas kernels for a TPU v5e that is described,
+not attached.
+
+``get_topology_desc`` describes the chip and the installed TPU compiler
+lowers and compiles for it, so what Mosaic refuses (an op it cannot lower,
+a kernel past the scoped-VMEM limit) fails here at no chip time. Nothing
+runs, so these tests say nothing about results or speed.
+
+The topology is described only inside the module fixture: one process at a
+time may load the TPU library, and every xdist worker imports this file.
+Keep these tests in this one file, so one worker loads it. The persistent
+compile cache is off around them: entries compiled for a described chip
+cannot be read back without one.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.blocks import MLPBlockConfig
+from repro.kernels.dense_block import stack
+from repro.kernels.replay_tree import ref as rt_ref
+from repro.kernels.replay_tree.replay_tree import tree_sample, tree_set_onehot
+
+CAPACITY, BATCH = 100_000, 256     # the paper budget's replay and batch
+
+# the widest (L, U) per connectivity that stack.py claims for the chip
+STACK_CLAIMS = [("densenet", 2, 1024), ("densenet", 4, 512),
+                ("densenet", 8, 128), ("d2rl", 2, 2048), ("d2rl", 8, 1024),
+                ("mlp", 2, 1024), ("mlp", 4, 512), ("mlp", 8, 256)]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")   # else libtpu logs to /tmp
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            jax.config.update("jax_enable_compilation_cache", was)
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
+
+
+def _compile_mosaic(fn, *args) -> None:
+    """Compile ``fn`` for the chip; the program must hold a Mosaic kernel."""
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+
+
+def test_tree_sample_compiles_for_v5e(one_chip):
+    size = rt_ref.tree_size(CAPACITY)
+    _compile_mosaic(
+        lambda t, x: tree_sample(t, x, capacity=CAPACITY, interpret=False),
+        jax.ShapeDtypeStruct((size,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((BATCH,), jnp.float32, sharding=one_chip))
+
+
+def test_tree_set_onehot_compiles_for_v5e(one_chip):
+    size = rt_ref.tree_size(CAPACITY)
+    _compile_mosaic(
+        lambda t, i, v: tree_set_onehot(t, i, v, interpret=False),
+        jax.ShapeDtypeStruct((size,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((BATCH,), jnp.float32, sharding=one_chip))
+
+
+@pytest.mark.parametrize("connectivity,layers,units", STACK_CLAIMS)
+def test_dense_stack_fwd_bwd_compiles_for_v5e(one_chip, connectivity, layers,
+                                              units):
+    d0 = 256
+    cfg = MLPBlockConfig(in_dim=d0, num_layers=layers, num_units=units,
+                         connectivity=connectivity)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def loss(x, ws, bs):
+        return jnp.sum(stack.dense_stack(
+            x, ws, bs, connectivity=connectivity, impl="pallas",
+            interpret=False) ** 2)
+
+    _compile_mosaic(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    shape(BATCH, d0),
+                    tuple(shape(d, units) for d in cfg.layer_in_dims()),
+                    tuple(shape(units) for _ in range(layers)))

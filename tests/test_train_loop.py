@@ -247,3 +247,35 @@ def test_sharded_runner_on_fake_mesh():
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr
     assert "OK" in r.stdout
+
+
+_MESH_TRACE = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import jax
+from repro.rl import ExperimentSpec
+from repro.rl.runner import Trainer
+
+spec = ExperimentSpec().override(
+    num_units=16, num_layers=1, use_ofenet=False, n_core=1, n_env=8,
+    batch_size=16, replay_capacity=512, warmup_steps=16,
+    replay_backend="device", loop="scan", mesh_shards=4)
+tr = Trainer(spec)
+ls, metrics, _ = jax.eval_shape(tr._superstep, tr.init_template())
+assert metrics["staleness_p50"].shape == ()
+print("OK")
+"""
+
+
+def test_actor_mesh_keeps_sharded_superstep_traceable():
+    """``make_actor_mesh`` must give axes the sharded superstep can trace
+    under: with ``Explicit`` axes (``jax.make_mesh``'s default) the median
+    of the data-sharded staleness vector refuses to trace."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (os.path.join(os.path.dirname(__file__), "..", "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    r = subprocess.run([sys.executable, "-c", _MESH_TRACE],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert "OK" in r.stdout
