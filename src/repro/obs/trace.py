@@ -55,7 +55,10 @@ class TraceCapture:
             return
         try:
             Path(self.trace_dir).mkdir(parents=True, exist_ok=True)
-            jax.profiler.start_trace(self.trace_dir)
+            # the Python tracer's per-call events slow the host several-fold
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
             self.active = True
             self.status = "active"
         except Exception as e:                   # pragma: no cover

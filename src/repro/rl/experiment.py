@@ -662,18 +662,22 @@ class Experiment:
                 with annotate("repro.chunk_dispatch"):
                     ls, out = trainer.chunk_fn(stop - step, do_eval,
                                                do_srank)(ls)
-                hstream = (jax.device_get(out["stream"])
-                           if "stream" in out else None)
+                hstream = None
+                if "stream" in out:
+                    with annotate("repro.obs.flush"):
+                        hstream = jax.device_get(out["stream"])
                 if mon is not None:
-                    viol = mon.check_stream(step, hstream) \
-                        if hstream is not None else []
-                    viol += mon.check_params(stop, ls.agent["params"])
+                    with annotate("repro.guard.check"):
+                        viol = mon.check_stream(step, hstream) \
+                            if hstream is not None else []
+                        viol += mon.check_params(stop, ls.agent["params"])
                     if viol:
                         obs.trace.end()
                         ls, step = self._guard_recover(viol, snap)
                         continue
                 if hstream is not None:
-                    obs.flush_chunk(step, hstream)
+                    with annotate("repro.obs.flush"):
+                        obs.flush_chunk(step, hstream)
                     obs.chunk_event(step, stop, time.time() - tc)
                 obs.trace.end()
                 step = stop
@@ -686,15 +690,17 @@ class Experiment:
                     self.sranks.append(srank)
                     obs.log_event("srank", step=step, srank=srank)
                     if mon is not None:
-                        viol = mon.check_srank(step, self.sranks)
+                        with annotate("repro.guard.check"):
+                            viol = mon.check_srank(step, self.sranks)
                         if viol:
                             ls, step = self._guard_recover(viol, snap)
                             continue
                 if want_last:
                     self._last_batch, self._last_priorities = out["last"]
                 if do_eval:
-                    ev_ret, scal = jax.device_get((out["eval"],
-                                                   out["scal"]))
+                    with annotate("repro.eval_readback"):
+                        ev_ret, scal = jax.device_get((out["eval"],
+                                                       out["scal"]))
                     self._record_eval(
                         step, float(np.mean(ev_ret)),
                         {k: float(v) for k, v in scal.items()}, progress)
@@ -965,9 +971,7 @@ class Experiment:
                 "max_priority": inner.max_priority,
                 "rng_state": self.trainer.rng.bit_generator.state,
             }
-        with annotate("repro.ckpt_save"):
-            ckpt.save(path, tree,
-                      metadata={"spec": self.spec.to_dict(),
-                                "experiment": state})
+        ckpt.save(path, tree,
+                  metadata={"spec": self.spec.to_dict(), "experiment": state})
         self._obs.log_event("save", step=self.step, path=str(path))
         self._obs.drain()

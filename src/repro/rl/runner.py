@@ -245,8 +245,7 @@ class Trainer:
 
         # ------------------------------------------- jitted python-loop ops
         w = self._count
-        self._update_j = w(jax.jit(
-            lambda st, b, k: self.update_fn(st, self.acfg, b, k)))
+        self._update_j = w(jax.jit(self._op_update))
         self.eval_j = w(jax.jit(lambda params, k: eval_returns(
             env, self.policy0.with_params(params), k, self.eval_episodes)))
         if self.use_device:
@@ -311,47 +310,60 @@ class Trainer:
                       steps: int, drop: int):
         """collect ``steps`` env steps and roll them through the n-step ring
         (identity for n_step == 1); returns store-schema transition rows."""
-        actors, trs = apex.collect(self.env, policy, params, actors, steps,
-                                   key)
-        if self.n_step == 1:
-            return actors, nstate, {k: trs[k] for k in _TRANSITION_FIELDS}
-        nstate, flat = nstep_emit_flat(self.n_step, self.gamma, nstate, trs,
-                                       steps, drop)
-        return actors, nstate, flat
+        with jax.named_scope("repro.collect"):
+            actors, trs = apex.collect(self.env, policy, params, actors,
+                                       steps, key)
+            if self.n_step == 1:
+                return actors, nstate, {k: trs[k]
+                                        for k in _TRANSITION_FIELDS}
+            nstate, flat = nstep_emit_flat(self.n_step, self.gamma, nstate,
+                                           trs, steps, drop)
+            return actors, nstate, flat
 
     # ------------------------------------------------- device backend ops
     def _op_collect_add(self, policy, params, actors, nstate, rstate, key,
                         step, *, steps: int, drop: int):
         if self.mesh is not None:
-            if self.n_step > 1:
-                return replay_sharded.collect_and_add_sharded(
+            # one fused call collects and adds on each shard: the add's
+            # scope covers both
+            with jax.named_scope("repro.replay.add"):
+                if self.n_step > 1:
+                    return replay_sharded.collect_and_add_sharded(
+                        self.env, policy, self.mesh, self.dcfg, params,
+                        actors, steps, key, rstate, nstep_state=nstate,
+                        gamma=self.gamma, step=step, drop=drop)
+                actors, rstate = replay_sharded.collect_and_add_sharded(
                     self.env, policy, self.mesh, self.dcfg, params, actors,
-                    steps, key, rstate, nstep_state=nstate, gamma=self.gamma,
-                    step=step, drop=drop)
-            actors, rstate = replay_sharded.collect_and_add_sharded(
-                self.env, policy, self.mesh, self.dcfg, params, actors,
-                steps, key, rstate, step=step)
-            return actors, nstate, rstate
+                    steps, key, rstate, step=step)
+                return actors, nstate, rstate
         actors, nstate, flat = self._collect_emit(
             policy, params, actors, nstate, key, steps=steps, drop=drop)
-        return actors, nstate, replay_add(self.dcfg, rstate, flat, step=step)
+        with jax.named_scope("repro.replay.add"):
+            return actors, nstate, replay_add(self.dcfg, rstate, flat,
+                                              step=step)
 
     def _op_sample(self, rstate, key, step):
-        if self.mesh is not None:
-            batch, idx, weights = replay_sharded.sharded_replay_sample(
-                self.dcfg, self.mesh, rstate, key, self.batch_size)
-        else:
-            batch, idx, weights = replay_sample(self.dcfg, rstate, key,
-                                                self.batch_size)
-        staleness = (step - batch.pop("add_step")).astype(jnp.float32)
+        with jax.named_scope("repro.replay.sample"):
+            if self.mesh is not None:
+                batch, idx, weights = replay_sharded.sharded_replay_sample(
+                    self.dcfg, self.mesh, rstate, key, self.batch_size)
+            else:
+                batch, idx, weights = replay_sample(self.dcfg, rstate, key,
+                                                    self.batch_size)
+            staleness = (step - batch.pop("add_step")).astype(jnp.float32)
         batch["weight"] = weights
         return batch, idx, staleness
 
+    def _op_update(self, agent, batch, key):
+        with jax.named_scope("repro.update"):
+            return self.update_fn(agent, self.acfg, batch, key)
+
     def _op_update_prio(self, rstate, idx, priorities):
-        if self.mesh is not None:
-            return replay_sharded.sharded_replay_update(
-                self.dcfg, self.mesh, rstate, idx, priorities)
-        return replay_update(self.dcfg, rstate, idx, priorities)
+        with jax.named_scope("repro.replay.refresh"):
+            if self.mesh is not None:
+                return replay_sharded.sharded_replay_update(
+                    self.dcfg, self.mesh, rstate, idx, priorities)
+            return replay_update(self.dcfg, rstate, idx, priorities)
 
     # --------------------------------------------- host backend callbacks
     def _cb_add(self, *arrs):
@@ -405,38 +417,44 @@ class Trainer:
         ``staleness=None`` (host replay: rows carry no add-step stamps)
         omits the staleness keys instead of reporting a bogus sentinel."""
         if staleness is not None:
-            metrics = dict(metrics,
-                           staleness_mean=staleness.mean(),
-                           staleness_p50=jnp.median(staleness),
-                           staleness_max=staleness.max())
+            with jax.named_scope("repro.replay.sample"):
+                metrics = dict(metrics,
+                               staleness_mean=staleness.mean(),
+                               staleness_p50=jnp.median(staleness),
+                               staleness_max=staleness.max())
         ls = TrainLoopState(agent, actors, nstate, rstate, key, ls.step + 1)
         return ls, metrics, batch
 
     def _superstep(self, ls: TrainLoopState):
         """One pure collect->add->sample->update->refresh step — the scan
         body. Host replay rides along via ordered io_callbacks on the SAME
-        buffer/rng the python loop uses, so the two loops stay seed-exact."""
+        buffer/rng the python loop uses, so the two loops stay seed-exact.
+        Each phase runs under its own ``jax.named_scope`` (``repro.collect``,
+        ``repro.replay.add``, ``repro.replay.sample``, ``repro.update``,
+        ``repro.replay.refresh``): op metadata only, so a profiler trace
+        can put device time on phases while the program stays the same."""
         if self.use_device:
             return self._device_step(
                 ls,
                 partial(self._op_collect_add, self._train_policy, steps=1,
                         drop=0),
-                self._op_sample,
-                lambda st, b, k: self.update_fn(st, self.acfg, b, k),
-                self._op_update_prio)
+                self._op_sample, self._op_update, self._op_update_prio)
         key, kc, ks, ku = jax.random.split(ls.key, 4)
         actors, nstate, flat = self._collect_emit(
             self._train_policy, ls.agent["params"], ls.actors, ls.nstep, kc,
             steps=1, drop=0)
-        io_callback(self._cb_add, jax.ShapeDtypeStruct((), jnp.int32),
-                    *[flat[f] for f in self._host_fields], ordered=True)
-        out = io_callback(self._cb_sample, self._host_sample_shapes(),
-                          ordered=True)
+        with jax.named_scope("repro.replay.add"):
+            io_callback(self._cb_add, jax.ShapeDtypeStruct((), jnp.int32),
+                        *[flat[f] for f in self._host_fields], ordered=True)
+        with jax.named_scope("repro.replay.sample"):
+            out = io_callback(self._cb_sample, self._host_sample_shapes(),
+                              ordered=True)
         batch = dict(zip(self._host_fields, out))
         idx, batch["weight"] = out[-2], out[-1]
-        agent, metrics = self.update_fn(ls.agent, self.acfg, batch, ku)
-        io_callback(self._cb_update, jax.ShapeDtypeStruct((), jnp.int32),
-                    idx, metrics["priorities"], ordered=True)
+        agent, metrics = self._op_update(ls.agent, batch, ku)
+        with jax.named_scope("repro.replay.refresh"):
+            io_callback(self._cb_update, jax.ShapeDtypeStruct((), jnp.int32),
+                        idx, metrics["priorities"], ordered=True)
         return self._finish_step(ls, agent, actors, nstate, ls.replay, key,
                                  None, metrics, batch)
 

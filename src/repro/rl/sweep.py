@@ -782,9 +782,8 @@ class Fleet:
             "dispatches": int(self.trainer.dispatches),
             "obs": [obs.state() for obs in self._obs],
         }
-        with annotate("repro.fleet_ckpt_save"):
-            ckpt.save(path, {_CKPT_KEY: _unkey(self._fls)},
-                      metadata={_CKPT_KEY: state})
+        ckpt.save(path, {_CKPT_KEY: _unkey(self._fls)},
+                  metadata={_CKPT_KEY: state})
         for obs in self._obs:
             obs.log_event("save", step=self.step, path=str(path))
             obs.drain()

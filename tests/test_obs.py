@@ -209,6 +209,42 @@ def test_trace_capture_lifecycle(tmp_path):
         pass
 
 
+def test_trace_capture_starts_without_the_python_tracer(tmp_path,
+                                                       monkeypatch):
+    seen = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, profiler_options=None: seen.append(
+                            profiler_options))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    tc = TraceCapture(1, str(tmp_path / "trace"))
+    tc.begin()
+    tc.end()
+    assert tc.status == "done"
+    (opts,) = seen
+    assert opts.python_tracer_level == 0
+
+
+def test_scan_driver_spans_its_blocking_host_steps(tmp_path, monkeypatch):
+    """The scan driver's host steps between dispatches each run inside a
+    named host span, so an ``obs.trace`` capture shows what the host did
+    while the device waited."""
+    import repro.rl.experiment as experiment
+    names = []
+    real = experiment.annotate
+
+    def record(name):
+        names.append(name)
+        return real(name)
+    monkeypatch.setattr(experiment, "annotate", record)
+    spec = _small(loop="scan", replay_backend="device", **_obs(
+        tmp_path, sinks=("memory",)), **{"guard.enabled": True})
+    Experiment.from_spec(spec).run(6)
+    # two chunks, each ending on an eval point (eval_every=3)
+    assert names == 2 * ["repro.chunk_dispatch", "repro.obs.flush",
+                         "repro.guard.check", "repro.obs.flush",
+                         "repro.eval_readback"]
+
+
 # ------------------------------------------------------- bitwise on/off
 
 @pytest.mark.parametrize("backend,loop", [("host", "python"),

@@ -1,9 +1,12 @@
 """Scan-superstep training loop tests: seed-for-seed parity between
 ``execution.loop="scan"`` and the per-step Python loop for BOTH replay
 backends, the host-dispatch bound, n-step return emission against a NumPy
-reference, the priority-staleness metric, the jitted eval rollout, and the
-4-fake-device mesh-sharded runner (subprocess, like test_substrate)."""
+reference, the priority-staleness metric, the jitted eval rollout, the
+superstep's phase scopes, and the 4-fake-device mesh-sharded runner
+(subprocess, like test_substrate)."""
+import contextlib
 import os
+import re
 import subprocess
 import sys
 
@@ -184,6 +187,37 @@ def test_eval_returns_matches_rollout_return():
               for i in range(3)]
     np.testing.assert_allclose(np.asarray(batched), np.asarray(legacy),
                                rtol=1e-5)
+
+
+# ------------------------------------------------------------ phase scopes
+
+PHASES = ("repro.collect", "repro.replay.add", "repro.replay.sample",
+          "repro.update", "repro.replay.refresh")
+
+
+def _lowered_chunk(backend):
+    exp = Experiment.from_spec(ExperimentSpec().override(
+        **_BASE, replay_backend=backend, loop="scan"))
+    exp._ensure_init()
+    return exp.trainer.chunk_fn(3, False).__wrapped__.lower(exp._ls)
+
+
+@pytest.mark.parametrize("backend", ["device", "host"])
+def test_chunk_names_each_phase_and_adds_no_instruction(backend,
+                                                        monkeypatch):
+    """Each superstep phase runs under its own ``jax.named_scope``, which
+    reaches the ops' metadata (the profiler's ``tf_op``) and nothing else:
+    lowered again with the scopes turned off, the chunk is the same
+    program once the printer leaves the locations out."""
+    scoped = _lowered_chunk(backend)
+    names = set(re.findall(r"repro\.[a-z.]+", scoped.as_text(debug_info=True)))
+    assert set(PHASES) <= names
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _lowered_chunk(backend)
+    assert not set(PHASES) & set(re.findall(
+        r"repro\.[a-z.]+", plain.as_text(debug_info=True)))
+    assert plain.as_text() == scoped.as_text()
 
 
 # ------------------------------------------------------------ sharded smoke
