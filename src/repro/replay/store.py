@@ -3,9 +3,13 @@
 The device-resident mirror of the host buffers' ``data`` dict: a pytree of
 preallocated ``(capacity, ...)`` arrays plus int32 write cursor and live
 count. All operations are pure functions (old state in, new state out) so the
-whole Ape-X ``add -> sample -> update`` loop jits into one device program —
-under jit the functional update lowers to an in-place dynamic-update-slice,
-no reallocation and no host round-trip.
+whole Ape-X ``add -> sample -> update`` loop jits into one device program.
+``store_add`` writes the appended block as contiguous rows: under jit it
+lowers to in-place dynamic-update-slices, with no reallocation and no host
+round-trip. A scatter of the same rows would make XLA:TPU carry a column one
+element wide (``act`` when ``act_dim`` is 1) lane-padded, 128x its size,
+and relayout the whole column for the sample's gather on every add; the
+slices keep every column in a compact layout.
 
 ``nstep_init``/``nstep_push``/``nstep_push_seq`` implement the Ape-X n-step
 return (Horgan et al. 2018, n=3 default) as a small per-actor rollback ring
@@ -48,6 +52,29 @@ def store_capacity(store: Store) -> int:
     return store["data"]["rew"].shape[0]
 
 
+def _write_block(v: jax.Array, rows: jax.Array, ptr: jax.Array) -> jax.Array:
+    """Write the ``n <= capacity`` ``rows`` to rows ``ptr .. ptr+n`` of
+    ``v``, wrapping past the end, as two dynamic-update-slices.
+
+    The block is rolled so that it lands whole at ``min(ptr, cap - n)``;
+    when it crosses the end, its first ``shift`` rows there belong to older
+    transitions and keep them, and the same rows of the rolled block are the
+    ones that wrap to rows ``0 .. shift``. ``shift`` is at most n - 1, so
+    only n - 1 old rows are read back: a one-row block reads nothing.
+    """
+    cap, n = v.shape[0], rows.shape[0]
+    start = jnp.minimum(ptr, cap - n)
+    shift = ptr - start
+    blk = jnp.roll(rows.astype(v.dtype), shift, axis=0)
+    wrap = (jnp.arange(n - 1) < shift).reshape((n - 1,) + (1,) * (v.ndim - 1))
+    older = jax.lax.dynamic_slice_in_dim(v, start, n - 1)
+    v = jax.lax.dynamic_update_slice_in_dim(
+        v, jnp.concatenate([jnp.where(wrap, older, blk[:-1]), blk[-1:]]),
+        start, 0)
+    return jax.lax.dynamic_update_slice_in_dim(
+        v, jnp.where(wrap, blk[:-1], v[:n - 1]), 0, 0)
+
+
 def store_add(store: Store, batch: Dict[str, jax.Array]
               ) -> tuple[Store, jax.Array]:
     """Append a transition batch at the cursor (wrapping); returns the
@@ -56,13 +83,12 @@ def store_add(store: Store, batch: Dict[str, jax.Array]
     n = batch["obs"].shape[0]
     ptr = store["ptr"]
     if n > cap:
-        # a batch that laps the buffer would scatter duplicate indices
-        # (unspecified winner in XLA) — keep only the last `cap` rows, the
+        # a batch that laps the buffer keeps only its last `cap` rows, the
         # host buffer's sequential last-write-wins outcome
         batch = {k: v[-cap:] for k, v in batch.items()}
-        ptr = ptr + (n - cap)
+        ptr = (ptr + (n - cap)) % cap
     idx = (ptr + jnp.arange(min(n, cap), dtype=jnp.int32)) % cap
-    data = {k: v.at[idx].set(batch[k].astype(v.dtype))
+    data = {k: _write_block(v, batch[k], ptr)
             for k, v in store["data"].items()}
     return {
         "data": data,

@@ -22,43 +22,57 @@ from _transitions import mk_batch as _mk_batch  # noqa: E402
 
 # ------------------------------------------------------------------- store
 
-def test_store_wraparound_matches_host_layout():
-    st = store_init(8, 3, 2)
-    st, _ = store_add(st, {k: jnp.asarray(v)
-                           for k, v in _mk_batch(8, seed=1).items()})
-    st, idx = store_add(st, {k: jnp.asarray(v)
-                             for k, v in _mk_batch(4, seed=2).items()})
-    host = PrioritizedReplay(8, 3, 2)
-    host.add_batch(_mk_batch(8, seed=1))
-    host.add_batch(_mk_batch(4, seed=2))
-    np.testing.assert_array_equal(np.asarray(st["data"]["obs"]),
-                                  host.data["obs"])
-    assert int(st["count"]) == len(host) == 8
-    np.testing.assert_array_equal(np.asarray(idx), np.arange(4))
+def _assert_columns_match(st, host):
+    assert set(st["data"]) == set(host.data)
+    for k, col in host.data.items():
+        np.testing.assert_array_equal(np.asarray(st["data"][k]), col,
+                                      err_msg=k)
+
+
+# (first, second) block sizes into capacity 8: the second block ends at the
+# end, or starts at capacity - 2 and crosses it
+@pytest.mark.parametrize("first,second", [(8, 4), (6, 5)])
+@pytest.mark.parametrize("act_dim", [1, 2])
+def test_store_wraparound_matches_host_layout(act_dim, first, second):
+    st = store_init(8, 3, act_dim)
+    b1 = _mk_batch(first, act_dim=act_dim, seed=1)
+    b2 = _mk_batch(second, act_dim=act_dim, seed=2)
+    st, _ = store_add(st, {k: jnp.asarray(v) for k, v in b1.items()})
+    st, idx = store_add(st, {k: jnp.asarray(v) for k, v in b2.items()})
+    host = PrioritizedReplay(8, 3, act_dim)
+    host.add_batch(b1)
+    host.add_batch(b2)
+    _assert_columns_match(st, host)
+    assert int(st["count"]) == len(host) == min(first + second, 8)
+    assert int(st["ptr"]) == host.ptr
+    np.testing.assert_array_equal(np.asarray(idx),
+                                  (first + np.arange(second)) % 8)
     got = store_gather(st, jnp.asarray([0, 5]))
-    np.testing.assert_array_equal(np.asarray(got["obs"]),
-                                  host.data["obs"][[0, 5]])
+    for k, col in host.data.items():
+        np.testing.assert_array_equal(np.asarray(got[k]), col[[0, 5]],
+                                      err_msg=k)
 
 
-def test_store_add_larger_than_capacity_matches_host():
+@pytest.mark.parametrize("act_dim", [1, 2])
+def test_store_add_larger_than_capacity_matches_host(act_dim):
     """A batch that laps the buffer keeps the last writes, like the host."""
-    st = store_init(8, 3, 2)
-    big = _mk_batch(20, seed=20)
+    st = store_init(8, 3, act_dim)
+    big = _mk_batch(20, act_dim=act_dim, seed=20)
     st, idx = store_add(st, {k: jnp.asarray(v) for k, v in big.items()})
-    host = PrioritizedReplay(8, 3, 2)
+    host = PrioritizedReplay(8, 3, act_dim)
     host.add_batch(big)
-    np.testing.assert_array_equal(np.asarray(st["data"]["obs"]),
-                                  host.data["obs"])
+    _assert_columns_match(st, host)
     assert int(st["count"]) == 8 and int(st["ptr"]) == 20 % 8 == host.ptr
     assert idx.shape == (8,)
     # priorities passed alongside an oversized batch stay row-aligned
-    cfg = DeviceReplayConfig(capacity=8, obs_dim=3, act_dim=2, alpha=1.0)
+    cfg = DeviceReplayConfig(capacity=8, obs_dim=3, act_dim=act_dim,
+                             alpha=1.0)
     pr = np.arange(1.0, 21.0, dtype=np.float32)
     state = replay_add(cfg, replay_init(cfg),
                        {k: jnp.asarray(v) for k, v in big.items()},
                        jnp.asarray(pr))
     leaves = np.asarray(sumtree_get(state["tree"], jnp.arange(8)))
-    hostp = PrioritizedReplay(8, 3, 2, alpha=1.0)
+    hostp = PrioritizedReplay(8, 3, act_dim, alpha=1.0)
     hostp.add_batch(big, pr)
     np.testing.assert_allclose(leaves, hostp.tree.get(np.arange(8)),
                                rtol=1e-5)

@@ -1,10 +1,11 @@
-"""Compile the RL path's Pallas kernels for a TPU v5e that is described,
-not attached.
+"""Compile the RL path's Pallas kernels, and the benchmark's training
+chunk, for a TPU v5e that is described, not attached.
 
 ``get_topology_desc`` describes the chip and the installed TPU compiler
 lowers and compiles for it, so what Mosaic refuses (an op it cannot lower,
-a kernel past the scoped-VMEM limit) fails here at no chip time. Nothing
-runs, so these tests say nothing about results or speed.
+a kernel past the scoped-VMEM limit) fails here at no chip time, and the
+compiled program's layouts and temporary memory can be read. Nothing runs,
+so these tests say nothing about results or speed.
 
 The topology is described only inside the module fixture: one process at a
 time may load the TPU library, and every xdist worker imports this file.
@@ -12,15 +13,21 @@ Keep these tests in this one file, so one worker loads it. The persistent
 compile cache is off around them: entries compiled for a described chip
 cannot be read back without one.
 """
+import json
+import re
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import repro.kernels
 from repro.core.blocks import MLPBlockConfig
 from repro.kernels.dense_block import stack
 from repro.kernels.replay_tree import ref as rt_ref
 from repro.kernels.replay_tree.replay_tree import tree_sample, tree_set_onehot
+from repro.rl.experiment import Experiment, ExperimentSpec
 
 CAPACITY, BATCH = 100_000, 256     # the paper budget's replay and batch
 
@@ -28,6 +35,11 @@ CAPACITY, BATCH = 100_000, 256     # the paper budget's replay and batch
 STACK_CLAIMS = [("densenet", 2, 1024), ("densenet", 4, 512),
                 ("densenet", 8, 128), ("d2rl", 2, 2048), ("d2rl", 8, 1024),
                 ("mlp", 2, 1024), ("mlp", 4, 512), ("mlp", 8, 256)]
+
+# the benchmark's training configuration: SAC 2x256, one actor, pendulum
+# (act_dim 1), 1e6-row device replay through the Pallas sum-tree
+TRAIN_CONFIG = (Path(__file__).resolve().parents[1]
+                / "bench" / "configs" / "sac-mlp-u256.json")
 
 
 @pytest.fixture(scope="module")
@@ -92,3 +104,28 @@ def test_dense_stack_fwd_bwd_compiles_for_v5e(one_chip, connectivity, layers,
                     shape(BATCH, d0),
                     tuple(shape(d, units) for d in cfg.layer_in_dims()),
                     tuple(shape(units) for _ in range(layers)))
+
+
+def test_train_chunk_keeps_replay_columns_compact_for_v5e(one_chip,
+                                                          monkeypatch):
+    """The superstep's replay add must not make XLA:TPU carry a store
+    column lane-padded: a scatter into the f32[capacity, 1] ``act`` column
+    got the (8, 128) tiling, 512 MB of temporaries for 4 MB of data, and a
+    relayout of the whole column on every update. A short chunk compiles to
+    the same layouts as the benchmark's 500-update one."""
+    # compile Mosaic kernels (not interpret mode) for the described chip
+    monkeypatch.setattr(repro.kernels, "mosaic_available", lambda: True)
+    spec = ExperimentSpec.from_dict(json.loads(TRAIN_CONFIG.read_text())
+                                    ["spec"])
+    trainer = Experiment.from_spec(spec).trainer
+    state = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: trainer._fresh_state()[0]))
+    compiled = jax.jit(trainer.chunk_fn(4, False).__wrapped__) \
+        .lower(state).compile()
+    hlo = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    cap = spec.replay.capacity
+    padded = re.findall(rf"f32\[{cap},1\]\{{1,0:T\(8,128\)", hlo)
+    assert not padded, f"{len(padded)} lane-padded replay columns"
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
