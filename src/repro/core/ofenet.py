@@ -13,6 +13,13 @@ the RL agent consumes features from the *online* network.
 Dimensionality intentionally grows: with densenet connectivity the emitted
 feature is dim(s) + L*U (e.g. 111 -> 2159 on Ant with L=8, U=256), matching
 Table 2 of the paper.
+
+Batch norm (``batch_norm``): the auxiliary step normalizes by the batch's
+statistics and refreshes the running ones (momentum 0.99, ``_bn_apply``);
+every other read of the features (acting, the critic targets and losses,
+the actor loss) normalizes by the running statistics. Every pass runs
+under the ``repro.ofenet`` name scope, so a profiler trace can put device
+time on it.
 """
 from __future__ import annotations
 
@@ -24,6 +31,9 @@ import jax.numpy as jnp
 
 from repro.common import Params, PRNGKey, dense_apply, dense_init, ema_update, split_keys
 from repro.core.blocks import MLPBlockConfig, mlp_block_apply, mlp_block_init
+from repro.optim import AdamWConfig, adamw_update
+
+SCOPE = "repro.ofenet"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,13 +90,16 @@ def features(params: Params, cfg: OFENetConfig, s: jax.Array,
              ) -> Tuple[jax.Array, Optional[jax.Array], Params]:
     """Compute (z_s, z_sa, refreshed-params). ``z_sa`` is None when ``a`` is None."""
     net = params[which]
-    z_s, _, new_phi_s = mlp_block_apply(
-        net["phi_s"], cfg.state_block, s, train=train, axis_name=axis_name)
-    z_sa, new_phi_sa = None, net["phi_sa"]
-    if a is not None:
-        z_sa, _, new_phi_sa = mlp_block_apply(
-            net["phi_sa"], cfg.sa_block, jnp.concatenate([z_s, a], axis=-1),
-            train=train, axis_name=axis_name)
+    with jax.named_scope(SCOPE):
+        z_s, _, new_phi_s = mlp_block_apply(
+            net["phi_s"], cfg.state_block, s, train=train,
+            axis_name=axis_name)
+        z_sa, new_phi_sa = None, net["phi_sa"]
+        if a is not None:
+            z_sa, _, new_phi_sa = mlp_block_apply(
+                net["phi_sa"], cfg.sa_block,
+                jnp.concatenate([z_s, a], axis=-1), train=train,
+                axis_name=axis_name)
     new_net = {**net, "phi_s": new_phi_s, "phi_sa": new_phi_sa}
     return z_s, z_sa, {**params, which: new_net}
 
@@ -104,3 +117,36 @@ def aux_loss(params: Params, cfg: OFENetConfig, s: jax.Array, a: jax.Array,
 
 def target_update(params: Params, cfg: OFENetConfig) -> Params:
     return {**params, "target": ema_update(params["target"], params["online"], cfg.tau)}
+
+
+def _with_stats(net: Params, fresh: Params) -> Params:
+    """``net`` with the batch-norm running statistics of ``fresh``."""
+    def block(b, f):
+        return {**b, "layers": [
+            {**l, "bn": {**l["bn"], "mean": fl["bn"]["mean"],
+                         "var": fl["bn"]["var"]}} if "bn" in l else l
+            for l, fl in zip(b["layers"], f["layers"])]}
+    return {**net, "phi_s": block(net["phi_s"], fresh["phi_s"]),
+            "phi_sa": block(net["phi_sa"], fresh["phi_sa"])}
+
+
+def aux_step(params: Params, cfg: OFENetConfig, opt_state, opt_cfg:
+             AdamWConfig, s: jax.Array, a: jax.Array, s_next: jax.Array):
+    """One Adam step of the online network on the auxiliary loss (eq. 1),
+    decoupled from RL, then the target's Polyak step. The batch-norm
+    running statistics (zero gradient: the loss reads batch statistics)
+    take the values the step's forward pass refreshed.
+
+    Returns ``(params, opt_state, loss, grads, online_after_adam)``."""
+    def loss_fn(online):
+        return aux_loss({**params, "online": online}, cfg, s, a, s_next)
+
+    with jax.named_scope(SCOPE):
+        (loss, fresh), g = jax.value_and_grad(loss_fn, has_aux=True)(
+            params["online"])
+        upd, opt_state = adamw_update(opt_cfg, g, opt_state,
+                                      params["online"])
+        if cfg.batch_norm:
+            upd = _with_stats(upd, fresh["online"])
+        new = target_update({**params, "online": upd}, cfg)
+    return new, opt_state, loss, g, upd

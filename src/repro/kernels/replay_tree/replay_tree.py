@@ -34,11 +34,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 _LANES = 128            # the tree is laid out (rows, 128) in VMEM
 _MXU_ROWS = 128         # levels this many rows wide use the one-hot matmul
 _CHUNK = 1 << 17        # nodes one one-hot matmul spans (512 KiB of f32)
+_SET_ROWS = 256         # leaves one set kernel writes (its masks are n x n)
+_SCOPED_VMEM = 16 << 20     # Mosaic's default scoped-VMEM limit (v5e)
+_KERNEL_TEMPS = 8 << 20     # a kernel's own temporaries, with room
+
+
+def _vmem_params(rows: jax.Array):
+    """Compiler parameters for a kernel that holds the whole tree in VMEM
+    (its input block, and a set's output block too). Where XLA has not
+    placed the tree in VMEM already (a program whose other ops take it),
+    the kernel stages those blocks itself: a 1e6-row tree (8 MiB) then
+    needs more than the default limit. ``None`` (the default) where two
+    trees and the temporaries fit it."""
+    need = 2 * rows.size * rows.dtype.itemsize + _KERNEL_TEMPS
+    if need <= _SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + _KERNEL_TEMPS)
 
 
 def _as_rows(tree: jax.Array) -> jax.Array:
@@ -129,6 +146,7 @@ def tree_sample(tree: jax.Array, targets: jax.Array, *, capacity: int,
             jax.ShapeDtypeStruct((b, 1), jnp.int32),
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
         ],
+        compiler_params=_vmem_params(rows),
         interpret=interpret,
     )(rows, targets.reshape(b, 1))
     return leaf[:, 0], pri[:, 0]
@@ -223,13 +241,38 @@ def tree_set_onehot(tree: jax.Array, idx: jax.Array, value: jax.Array, *,
     movement is dense matmuls, masked reductions and static slices of the
     ``(rows, 128)`` tree. ``chunk`` bounds the nodes one one-hot matmul
     spans on wide levels (must be a power of two).
+
+    More than ``_SET_ROWS`` leaves (a replay warm-up) are set in pieces of
+    that many, in order, by a scan: the kernel's ``(n, n)`` duplicate masks
+    do not compile at ~10 000 leaves. Later pieces overwrite earlier ones,
+    so keep-last holds across pieces; ancestor sums may differ from one
+    set's by rounding.
     """
+    assert chunk & (chunk - 1) == 0, chunk
+    (n,) = idx.shape
+    idx = idx.astype(jnp.int32)
+    if n <= _SET_ROWS:
+        return _set_onehot(tree, idx, value, interpret=interpret, chunk=chunk)
+    k = n // _SET_ROWS
+    head = k * _SET_ROWS
+    tree, _ = jax.lax.scan(
+        lambda t, piece: (_set_onehot(t, *piece, interpret=interpret,
+                                      chunk=chunk), None),
+        tree, (idx[:head].reshape(k, _SET_ROWS),
+               value[:head].reshape(k, _SET_ROWS)))
+    if n > head:
+        tree = _set_onehot(tree, idx[head:], value[head:],
+                           interpret=interpret, chunk=chunk)
+    return tree
+
+
+def _set_onehot(tree: jax.Array, idx: jax.Array, value: jax.Array, *,
+                interpret: bool, chunk: int) -> jax.Array:
+    """One kernel call of ``tree_set_onehot`` (int32 ``idx``)."""
     size = tree.shape[0]
     depth = size.bit_length() - 1
     (n,) = idx.shape
-    assert chunk & (chunk - 1) == 0, chunk
     rows = _as_rows(tree)
-    idx = idx.astype(jnp.int32)
     full = lambda shape: pl.BlockSpec(shape, lambda i: (0, 0))
     out = pl.pallas_call(
         functools.partial(_set_onehot_kernel, depth=depth,
@@ -240,6 +283,7 @@ def tree_set_onehot(tree: jax.Array, idx: jax.Array, value: jax.Array, *,
         out_specs=full(rows.shape),
         out_shape=jax.ShapeDtypeStruct(rows.shape, tree.dtype),
         input_output_aliases={0: 0},
+        compiler_params=_vmem_params(rows),
         interpret=interpret,
     )(rows, idx.reshape(n, 1), idx.reshape(1, n), value.reshape(n, 1))
     return out.reshape(-1)[:size]

@@ -246,12 +246,146 @@ def make_acrobot() -> EnvSpec:
     return EnvSpec("acrobot", 6, 1, 200, reset, step, obs)
 
 
+# ---------------------------------------------------------------------------
+# Quadruped: a torso on four two-joint legs, at Ant-v2's observation and
+# action widths (111 / 8) and reward terms
+# ---------------------------------------------------------------------------
+
+QUADRUPED = dict(
+    substeps=5, dt=0.01,                 # Ant-v2: frame_skip 5 x 0.01 s
+    gear=150.0, inertia=20.0, damping=10.0,   # joint: tau = gear * a
+    hip_range=0.52, ankle_range=(0.52, 1.22),  # rad, Ant-v2's 30 and 70 deg
+    leg_angles=(0.25, 0.75, 1.25, 1.75),      # x pi: the legs' directions
+    upper=0.28, lower=0.57,              # horizontal reach, shin length (m)
+    mass=1.0, gravity=9.81, tilt_inertia=0.5, tilt_damping=2.0,
+    contact_k=400.0, contact_d=10.0, friction=1.0, yaw_gain=0.5,
+    z0=0.75, ankle0=1.0, healthy_z=(0.2, 1.0), episode=1000)
+
+
+def make_quadruped() -> EnvSpec:
+    """A torso on four legs (hip in the horizontal plane, ankle lifting the
+    shin), at Ant-v2's widths: observation 13 positions (torso height,
+    orientation quaternion, 8 joint angles; no x/y), 14 velocities (torso
+    linear and angular, 8 joints) and 84 external contact-force entries
+    (14 bodies x [torque, force], Ant-v2's ``cfrc_ext`` layout, only the
+    four feet non-zero) clipped to [-1, 1]; action 8 joint torques in
+    [-1, 1]; dt 0.05 s as 5 steps of 0.01 s.
+
+    Dynamics, semi-implicit Euler per step, all small-angle:
+
+    * joints: ``qdd = (gear a - damping qd - contact load) / inertia``,
+      clipped to their ranges (velocity zeroed at a stop);
+    * a foot sits ``lower sin(ankle)`` below the torso, tilted by roll and
+      pitch; below the ground it pushes with ``k pen - d vz`` (no pull);
+    * the torso rises with the feet's normal forces, tilts with their
+      moments (damped), and slides toward the velocity the grounded feet's
+      hip swing drives it to, each foot weighted by its normal force
+      (friction); hip swing on the ground also turns it (yaw).
+
+    Reward (Ant-v2): forward velocity - 0.5 |a|^2 - 5e-4 |clip(cfrc)|^2 +
+    1 while the torso height is in [0.2, 1]. Episodes end at 1 000 steps
+    only (``done`` is never set)."""
+    c = QUADRUPED
+    dt = c["dt"]
+    phi = jnp.pi * jnp.asarray(c["leg_angles"], jnp.float32)
+    lo = jnp.asarray([-c["hip_range"]] * 4 + [c["ankle_range"][0]] * 4)
+    hi = jnp.asarray([c["hip_range"]] * 4 + [c["ankle_range"][1]] * 4)
+
+    def feet(q):
+        """Feet offsets from the torso (x, y, z; world axes up to yaw)."""
+        hip, ankle = q[6:10], q[10:14]
+        reach = c["upper"] + c["lower"] * jnp.cos(ankle)
+        ang = phi + hip + q[5]
+        px, py = reach * jnp.cos(ang), reach * jnp.sin(ang)
+        pz = -c["lower"] * jnp.sin(ankle) + py * q[3] - px * q[4]
+        return px, py, pz, reach, ang
+
+    def contact(q, qd):
+        """Normal force per foot and the cfrc_ext rows (14, 6)."""
+        px, py, pz, reach, ang = feet(q)
+        pen = jnp.maximum(-(q[2] + pz), 0.0)
+        fn = jnp.where(pen > 0, jnp.maximum(
+            c["contact_k"] * pen - c["contact_d"] * qd[2], 0.0), 0.0)
+        # grounded feet drive the torso against their hip swing
+        sweep = qd[6:10] * reach
+        dvx = sweep * jnp.sin(ang) - qd[0]
+        dvy = -sweep * jnp.cos(ang) - qd[1]
+        share = jnp.minimum(dt * c["friction"] * fn / c["mass"], 0.25)
+        fx = share * dvx * c["mass"] / dt
+        fy = share * dvy * c["mass"] / dt
+        wrench = jnp.stack([py * fn - pz * fy, pz * fx - px * fn,
+                            px * fy - py * fx, fx, fy, fn], -1)
+        # bodies: world, torso, then (hip, thigh, foot) per leg
+        rows = jnp.zeros((14, 6)).at[jnp.asarray([4, 7, 10, 13])].set(wrench)
+        return fn, share, dvx, dvy, px, py, reach, rows
+
+    def substep(q, qd, a):
+        fn, share, dvx, dvy, px, py, _, _ = contact(q, qd)
+        load = jnp.concatenate(
+            [jnp.zeros(4), fn * c["lower"] * jnp.cos(q[10:14])])
+        jd = qd[6:] + dt * (c["gear"] * a - c["damping"] * qd[6:]
+                            - load) / c["inertia"]
+        j = q[6:] + dt * jd
+        stop = (j < lo) | (j > hi)
+        j, jd = jnp.clip(j, lo, hi), jnp.where(stop, 0.0, jd)
+        vx = qd[0] + jnp.sum(share * dvx)
+        vy = qd[1] + jnp.sum(share * dvy)
+        vz = qd[2] + dt * (jnp.sum(fn) / c["mass"] - c["gravity"])
+        wr = qd[3] + dt * (jnp.sum(fn * py) / c["tilt_inertia"]
+                           - c["tilt_damping"] * qd[3])
+        wp = qd[4] + dt * (-jnp.sum(fn * px) / c["tilt_inertia"]
+                           - c["tilt_damping"] * qd[4])
+        wy = qd[5] + jnp.sum(share * (-c["yaw_gain"] * qd[6:10] - qd[5]))
+        v = jnp.stack([vx, vy, vz, wr, wp, wy])
+        base = q[:6] + dt * v
+        base = base.at[3:5].set(jnp.clip(base[3:5], -1.0, 1.0))
+        return (jnp.concatenate([base, j]), jnp.concatenate([v, jd]))
+
+    def quat(r, p, y):
+        cr, sr = jnp.cos(0.5 * r), jnp.sin(0.5 * r)
+        cp, sp = jnp.cos(0.5 * p), jnp.sin(0.5 * p)
+        cy, sy = jnp.cos(0.5 * y), jnp.sin(0.5 * y)
+        return jnp.stack([cr * cp * cy + sr * sp * sy,
+                          sr * cp * cy - cr * sp * sy,
+                          cr * sp * cy + sr * cp * sy,
+                          cr * cp * sy - sr * sp * cy])
+
+    def obs(s: EnvState):
+        cfrc = jnp.clip(contact(s.q, s.qd)[-1], -1.0, 1.0)
+        return jnp.concatenate([s.q[2:3], quat(s.q[3], s.q[4], s.q[5]),
+                                s.q[6:], s.qd, cfrc.reshape(-1)])
+
+    def reset(key):
+        k1, k2, k3 = jax.random.split(key, 3)
+        q0 = jnp.asarray([0.0, 0.0, c["z0"], 0.0, 0.0, 0.0]
+                         + [0.0] * 4 + [c["ankle0"]] * 4)
+        q = q0 + jax.random.uniform(k1, (14,), minval=-0.1, maxval=0.1)
+        return _mk_state(k3, q, 0.1 * jax.random.normal(k2, (14,)))
+
+    def step(s: EnvState, a: jax.Array):
+        a = jnp.clip(a, -1, 1)
+        q, qd = s.q, s.qd
+        for _ in range(c["substeps"]):
+            q, qd = substep(q, qd, a)
+        ns = EnvState(q=q, qd=qd, t=s.t + 1, key=s.key)
+        cfrc = jnp.clip(contact(q, qd)[-1], -1.0, 1.0)
+        healthy = (q[2] >= c["healthy_z"][0]) & (q[2] <= c["healthy_z"][1])
+        reward = ((q[0] - s.q[0]) / (c["substeps"] * dt)
+                  - 0.5 * jnp.sum(jnp.square(a))
+                  - 5e-4 * jnp.sum(jnp.square(cfrc))
+                  + healthy.astype(jnp.float32))
+        return ns, obs(ns), reward, jnp.bool_(False)
+
+    return EnvSpec("quadruped", 111, 8, c["episode"], reset, step, obs)
+
+
 ENVS: Dict[str, Callable[[], EnvSpec]] = {
     "pendulum": make_pendulum,
     "cartpole_swingup": make_cartpole_swingup,
     "reacher2": make_reacher2,
     "pointmass": make_pointmass,
     "acrobot": make_acrobot,
+    "quadruped": make_quadruped,
 }
 
 

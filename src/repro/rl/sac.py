@@ -5,6 +5,11 @@ Policy and twin Q-networks are MLP blocks with selectable connectivity
 raw (s, a) or OFENet features (z_s, z_sa) (§3.1). Hyperparameters follow
 Haarnoja et al. 2018 (lr 3e-4, tau 5e-3, gamma 0.99, auto entropy tuning);
 Huber loss on the critic per paper A.1.
+
+Inside the Trainer's ``repro.update`` scope the update names its parts for
+a profiler trace: ``repro.update.critic`` (target, loss, Adam step, target
+average and priorities), ``repro.update.actor``, ``repro.update.alpha``;
+OFENet's passes carry ``repro.ofenet`` (``core/ofenet.py``).
 """
 from __future__ import annotations
 
@@ -160,15 +165,8 @@ def sac_update(state: Params, cfg: SACConfig, batch: Dict[str, jax.Array],
 
     # --- OFENet auxiliary update (decoupled from RL; eq. 1) ---------------
     if cfg.ofenet is not None:
-        def ofe_loss(online):
-            pk = {**params["ofenet"], "online": online}
-            loss, _ = ofe.aux_loss(pk, cfg.ofenet, s, a, s2)
-            return loss
-        l_aux, g = jax.value_and_grad(ofe_loss)(params["ofenet"]["online"])
-        upd, opt_ofe = adamw_update(opt_cfg, g, opt["ofenet"],
-                                    params["ofenet"]["online"])
-        ofep = {**params["ofenet"], "online": upd}
-        ofep = ofe.target_update(ofep, cfg.ofenet)
+        ofep, opt_ofe, l_aux, g, upd = ofe.aux_step(
+            params["ofenet"], cfg.ofenet, opt["ofenet"], opt_cfg, s, a, s2)
         new_params["ofenet"] = ofep
         new_opt["ofenet"] = opt_ofe
         metrics["aux_loss"] = l_aux
@@ -180,29 +178,31 @@ def sac_update(state: Params, cfg: SACConfig, batch: Dict[str, jax.Array],
 
     # --- critic update -----------------------------------------------------
     alpha = jnp.exp(params["log_alpha"])
-    a2, logp2 = sample_action(work, cfg, s2, k1)
-    q1_t, q2_t, _ = q_values(params["target_critics"], work, cfg, s2, a2)
-    # bootstrap coefficient: gamma^span * (1 - done). n-step batches carry it
-    # precomputed as "disc" (repro.replay.store.nstep_push); 1-step falls
-    # back to the usual gamma * (1 - done)
-    disc = batch.get("disc")
-    if disc is None:
-        disc = cfg.gamma * (1.0 - d)
-    q_target = r + disc * (jnp.minimum(q1_t, q2_t) - alpha * logp2)
-    q_target = jax.lax.stop_gradient(q_target)
+    with jax.named_scope("repro.update.critic"):
+        a2, logp2 = sample_action(work, cfg, s2, k1)
+        q1_t, q2_t, _ = q_values(params["target_critics"], work, cfg, s2,
+                                 a2)
+        # bootstrap coefficient: gamma^span * (1 - done). n-step batches
+        # carry it precomputed as "disc" (repro.replay.store.nstep_push);
+        # 1-step falls back to the usual gamma * (1 - done)
+        disc = batch.get("disc")
+        if disc is None:
+            disc = cfg.gamma * (1.0 - d)
+        q_target = r + disc * (jnp.minimum(q1_t, q2_t) - alpha * logp2)
+        q_target = jax.lax.stop_gradient(q_target)
 
-    def critic_loss(critics):
-        q1, q2, _ = q_values(critics, work, cfg, s, a)
-        e1, e2 = q1 - q_target, q2 - q_target
-        l1, l2 = (huber(e1), huber(e2)) if cfg.huber \
-            else (0.5 * e1 ** 2, 0.5 * e2 ** 2)
-        if w_is is not None:
-            return jnp.mean(w_is * l1) + jnp.mean(w_is * l2)
-        return jnp.mean(l1) + jnp.mean(l2)
+        def critic_loss(critics):
+            q1, q2, _ = q_values(critics, work, cfg, s, a)
+            e1, e2 = q1 - q_target, q2 - q_target
+            l1, l2 = (huber(e1), huber(e2)) if cfg.huber \
+                else (0.5 * e1 ** 2, 0.5 * e2 ** 2)
+            if w_is is not None:
+                return jnp.mean(w_is * l1) + jnp.mean(w_is * l2)
+            return jnp.mean(l1) + jnp.mean(l2)
 
-    l_q, g_q = jax.value_and_grad(critic_loss)(params["critics"])
-    critics, opt_c = adamw_update(opt_cfg, g_q, opt["critics"],
-                                  params["critics"])
+        l_q, g_q = jax.value_and_grad(critic_loss)(params["critics"])
+        critics, opt_c = adamw_update(opt_cfg, g_q, opt["critics"],
+                                      params["critics"])
     new_params["critics"] = critics
     new_opt["critics"] = opt_c
     if cfg.grad_norms:
@@ -217,9 +217,11 @@ def sac_update(state: Params, cfg: SACConfig, batch: Dict[str, jax.Array],
         q1, q2, _ = q_values(critics, w, cfg, s, ai)
         return jnp.mean(alpha * logp - jnp.minimum(q1, q2)), logp
 
-    (l_pi, logp), g_pi = jax.value_and_grad(actor_loss, has_aux=True)(
-        params["actor"])
-    actor, opt_a = adamw_update(opt_cfg, g_pi, opt["actor"], params["actor"])
+    with jax.named_scope("repro.update.actor"):
+        (l_pi, logp), g_pi = jax.value_and_grad(actor_loss, has_aux=True)(
+            params["actor"])
+        actor, opt_a = adamw_update(opt_cfg, g_pi, opt["actor"],
+                                    params["actor"])
     new_params["actor"] = actor
     new_opt["actor"] = opt_a
     if cfg.grad_norms:
@@ -231,19 +233,19 @@ def sac_update(state: Params, cfg: SACConfig, batch: Dict[str, jax.Array],
     def alpha_loss(log_alpha):
         return -jnp.mean(jnp.exp(log_alpha)
                          * jax.lax.stop_gradient(logp + target_entropy))
-    l_al, g_al = jax.value_and_grad(alpha_loss)(params["log_alpha"])
-    log_alpha, opt_al = adamw_update(opt_cfg, g_al, opt["alpha"],
-                                     params["log_alpha"])
+    with jax.named_scope("repro.update.alpha"):
+        l_al, g_al = jax.value_and_grad(alpha_loss)(params["log_alpha"])
+        log_alpha, opt_al = adamw_update(opt_cfg, g_al, opt["alpha"],
+                                         params["log_alpha"])
     new_params["log_alpha"] = log_alpha
     new_opt["alpha"] = opt_al
 
-    # --- target nets ---------------------------------------------------------
-    new_params["target_critics"] = ema_update(
-        params["target_critics"], critics, cfg.tau)
-
-    # priorities for PER: TD error magnitude
-    q1, q2, feat = q_values(critics, work, cfg, s, a)
-    td = jnp.abs(q1 - q_target)
+    # --- target nets; priorities for PER: TD error magnitude ---------------
+    with jax.named_scope("repro.update.critic"):
+        new_params["target_critics"] = ema_update(
+            params["target_critics"], critics, cfg.tau)
+        q1, q2, feat = q_values(critics, work, cfg, s, a)
+        td = jnp.abs(q1 - q_target)
     metrics.update({"critic_loss": l_q, "actor_loss": l_pi,
                     "alpha": jnp.exp(log_alpha), "q_mean": jnp.mean(q1),
                     "td_error": jnp.mean(td)})

@@ -122,15 +122,8 @@ def td3_update(state: Params, cfg: TD3Config, batch: Dict[str, jax.Array],
     new_opt = dict(opt)
 
     if cfg.ofenet is not None:
-        def ofe_loss(online):
-            pk = {**params["ofenet"], "online": online}
-            loss, _ = ofe.aux_loss(pk, cfg.ofenet, s, a, s2)
-            return loss
-        l_aux, g = jax.value_and_grad(ofe_loss)(params["ofenet"]["online"])
-        upd, opt_ofe = adamw_update(opt_cfg, g, opt["ofenet"],
-                                    params["ofenet"]["online"])
-        ofep = ofe.target_update({**params["ofenet"], "online": upd},
-                                 cfg.ofenet)
+        ofep, opt_ofe, l_aux, g, upd = ofe.aux_step(
+            params["ofenet"], cfg.ofenet, opt["ofenet"], opt_cfg, s, a, s2)
         new_params["ofenet"] = ofep
         new_opt["ofenet"] = opt_ofe
         metrics["aux_loss"] = l_aux

@@ -40,6 +40,7 @@ STACK_CLAIMS = [("densenet", 2, 1024), ("densenet", 4, 512),
 # (act_dim 1), 1e6-row device replay through the Pallas sum-tree
 TRAIN_CONFIG = (Path(__file__).resolve().parents[1]
                 / "bench" / "configs" / "sac-mlp-u256.json")
+PAPER_CONFIG = TRAIN_CONFIG.with_name("sac-densenet-u2048.json")
 
 
 @pytest.fixture(scope="module")
@@ -85,6 +86,55 @@ def test_tree_set_onehot_compiles_for_v5e(one_chip):
         jax.ShapeDtypeStruct((size,), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((BATCH,), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((BATCH,), jnp.float32, sharding=one_chip))
+
+
+def _paper_agent(monkeypatch, **override):
+    """The Trainer of the paper's agent (2x2048 DenseNet SAC with OFENet,
+    64 actors, 111/8 widths, 1e6-row replay through the Pallas sum-tree),
+    with Mosaic kernels compiled for the described chip."""
+    monkeypatch.setattr(repro.kernels, "mosaic_available", lambda: True)
+    spec = ExperimentSpec.from_dict(json.loads(PAPER_CONFIG.read_text())
+                                    ["spec"]).override(**override)
+    return Experiment.from_spec(spec).trainer
+
+
+def _on_chip(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.mark.parametrize("capacity", [CAPACITY, 1_000_000])
+def test_tree_kernels_ask_for_room_for_a_large_tree(one_chip, monkeypatch,
+                                                    capacity):
+    """In the paper agent's training chunk XLA does not place the tree in
+    VMEM before the sum-tree kernels, so each stages its own copy: at 1e6
+    rows (8 MiB) the add's 64-row set asks for 22.97 MiB and does not
+    compile at Mosaic's default 16 MiB limit. The kernels ask for the room
+    a large tree needs; a small tree keeps the default."""
+    trainer = _paper_agent(monkeypatch, replay_capacity=capacity)
+    state = _on_chip(jax.eval_shape(lambda: trainer._fresh_state()[0]),
+                     one_chip)
+    hlo = jax.jit(trainer.chunk_fn(2, False).__wrapped__).lower(state) \
+        .compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+
+
+def test_paper_agent_warmup_compiles_for_v5e(one_chip, monkeypatch):
+    """The warm-up adds its 9 984 rows in one replay add; the one-hot
+    sum-tree set takes them in pieces (its (n, n) masks do not compile at
+    that many leaves)."""
+    trainer = _paper_agent(monkeypatch)
+    warm = trainer.warmup_steps // trainer.n_actors
+
+    def warmup(seed, key):
+        ls, _ = trainer._fresh_state(seed)
+        return trainer._op_collect_add(
+            trainer._rand_policy, ls.agent["params"], ls.actors, ls.nstep,
+            ls.replay, key, ls.step, steps=warm, drop=0)
+    _compile_mosaic(
+        warmup, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jax.random.key(0).dtype, sharding=one_chip))
 
 
 @pytest.mark.parametrize("connectivity,layers,units", STACK_CLAIMS)
