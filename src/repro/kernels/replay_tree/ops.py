@@ -1,9 +1,12 @@
 """Jit'd dispatch layer for the device sum-tree: Pallas kernel or XLA ref.
 
-``backend="pallas"`` runs the fused descent/scatter kernels from
-``replay_tree.py`` (interpret mode on CPU); ``backend="xla"`` runs the pure
-jnp oracle from ``ref.py`` — the same functions the tests use as ground
-truth, and the sensible default on CPU where interpret-mode Pallas is slow.
+``backend="pallas"`` runs the kernels from ``replay_tree.py`` (interpret
+mode on CPU): the sample's two-stage descent (the tree's top levels from a
+VMEM block, the deeper ones, at most 7, from rows DMA'd by the node ids
+the top stage found; the split follows from the tree's depth) and the
+one-hot set. ``backend="xla"`` runs the pure jnp oracle from ``ref.py`` —
+the same functions the tests use as ground truth, and the sensible
+default on CPU where interpret-mode Pallas is slow.
 ``repro.replay`` calls only through this layer, so the replay subsystem is
 backend-agnostic. Asking for ``backend="pallas", interpret=False`` where
 Mosaic cannot lower (off-TPU) raises instead of running something else.
@@ -64,8 +67,9 @@ def sumtree_sample(tree: jax.Array, targets: jax.Array, *, capacity: int,
                    interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
     """Batch proportional descent -> (leaf_idx, leaf_priority).
 
-    Targets are padded up to a multiple of the kernel's batch tile ``bt``;
-    the pad lanes descend with target 0 and are sliced off.
+    The Pallas descent returns the oracle's leaves and stored priorities
+    bit for bit. Targets are padded up to a multiple of the kernel's batch
+    tile ``bt``; the pad lanes descend with target 0 and are sliced off.
     """
     assert backend in BACKENDS, backend
     (b,) = targets.shape

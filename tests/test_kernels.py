@@ -184,23 +184,45 @@ def test_replay_tree_set_kernel_matches_ref(capacity):
                                rtol=1e-6)
 
 
-@pytest.mark.parametrize("capacity,bt", [(37, 16), (128, 64), (1000, 128),
-                                         (20000, 128)])
-def test_replay_tree_sample_kernel_matches_ref(capacity, bt):
+@pytest.mark.parametrize("capacity,bt,fill", [
+    (37, 16, "uniform"), (128, 64, "uniform"), (1000, 128, "uniform"),
+    (20000, 128, "uniform"), (100_000, 128, "uniform"),
+    (250_000, 128, "uniform"), (1_000_000, 128, "uniform"),
+    (1_000_000, 128, "warmup")])
+def test_replay_tree_sample_kernel_matches_ref(capacity, bt, fill):
     """Capacity 20000 puts the leaf level in 256 rows of 128 nodes: the
-    one-hot-matmul row lookup path, not just the VPU row selects."""
+    one-hot-matmul row lookup path, not just the VPU row selects. From
+    capacity 100 000 (depth 18) on, the levels below the top stage come
+    from rows fetched by DMA: one row level at 100 000, two at 250 000 (a
+    four-shard slice of 1e6), four at 1e6. ``warmup`` is a replay after its
+    warm-up: 10 048 rows at the initial priority, then the zero tail.
+    Targets are stratified as the replay draws them, plus ``total`` itself
+    (which walks into the zero tail and is clamped); in ``warmup`` the
+    first 16 are the integers 1..16, each equal to a left mass on the leaf
+    level or one of the four above it (``t >= lmass`` goes right there)."""
     rng = np.random.default_rng(11)
-    pr = jnp.asarray(rng.uniform(0.0, 3.0, capacity), jnp.float32)
+    if fill == "warmup":
+        n = 10_048
+        pr = jnp.ones((n,), jnp.float32)
+    else:
+        n = capacity
+        pr = jnp.asarray(rng.uniform(0.0, 3.0, capacity), jnp.float32)
     tree = rt_ref.tree_set_ref(rt_ref.tree_init_ref(capacity),
-                               jnp.arange(capacity), pr)
-    total = float(rt_ref.tree_total_ref(tree))
+                               jnp.arange(n), pr)
+    total = rt_ref.tree_total_ref(tree)
     b = 2 * bt
-    targets = jnp.asarray(rng.uniform(0.0, total, b), jnp.float32)
+    u = jnp.asarray(rng.uniform(0.0, 1.0, b), jnp.float32)
+    targets = (jnp.arange(b, dtype=jnp.float32) + u) * (total / b)
+    targets = targets.at[-1].set(total)
+    if fill == "warmup":
+        targets = targets.at[:16].set(jnp.arange(1, 17, dtype=jnp.float32))
     leaf_k, pri_k = tree_sample(tree, targets, capacity=capacity, bt=bt)
     leaf_r = rt_ref.tree_sample_ref(tree, targets, capacity=capacity)
     np.testing.assert_array_equal(np.asarray(leaf_k), np.asarray(leaf_r))
-    np.testing.assert_allclose(np.asarray(pri_k),
-                               np.asarray(pr)[np.asarray(leaf_k)], rtol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(pri_k), np.asarray(rt_ref.tree_get_ref(tree, leaf_r)))
+    if fill == "warmup":                # total walks the zero tail's right edge
+        assert int(leaf_k[-1]) == capacity - 1
 
 
 @pytest.mark.parametrize("capacity,chunk", [(5, 1024), (37, 1024), (64, 16),

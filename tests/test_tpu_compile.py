@@ -71,12 +71,28 @@ def _compile_mosaic(fn, *args) -> None:
     assert 'custom_call_target="tpu_custom_call"' in hlo
 
 
-def test_tree_sample_compiles_for_v5e(one_chip):
-    size = rt_ref.tree_size(CAPACITY)
-    _compile_mosaic(
-        lambda t, x: tree_sample(t, x, capacity=CAPACITY, interpret=False),
-        jax.ShapeDtypeStruct((size,), jnp.float32, sharding=one_chip),
-        jax.ShapeDtypeStruct((BATCH,), jnp.float32, sharding=one_chip))
+@pytest.mark.parametrize("capacity", [CAPACITY, 1_000_000])
+def test_tree_sample_compiles_for_v5e(one_chip, capacity):
+    """The sample stages no whole tree: neither the program's temporaries
+    nor any of its kernels' scoped VMEM reach a 1e6-row tree's 8 MiB (the
+    whole-tree one-hot descent used 10.75 MiB of VMEM at 1e6 rows)."""
+    size = rt_ref.tree_size(capacity)
+    limit = 4 * rt_ref.tree_size(1_000_000)
+    compiled = jax.jit(
+        lambda t, x: tree_sample(t, x, capacity=capacity, interpret=False)
+    ).lower(jax.ShapeDtypeStruct((size,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((BATCH,), jnp.float32, sharding=one_chip)
+            ).compile()
+    hlo = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+    kernels = [line for line in hlo.splitlines()
+               if re.match(r"\s*%tree_sample\.\d+ = .* custom-call\(", line)]
+    assert kernels
+    for line in kernels:
+        used = re.search(r'"used_scoped_memory_configs":\[[^]]*"size":"(\d+)"',
+                         line)
+        assert used and int(used.group(1)) < limit, line[:200]
 
 
 def test_tree_set_onehot_compiles_for_v5e(one_chip):
