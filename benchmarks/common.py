@@ -94,4 +94,5 @@ def fleet_rows(sweep, name_fn, extra_fn=None) -> List[Dict]:
 
 def print_rows(rows: List[Dict]) -> None:
     for r in rows:
-        print(f"{r['name']},{r['us_per_call']:.0f},{r['derived']}")
+        print(f"{r['name']},{r['us_per_call']:.0f},"
+              f"{r.get('derived', '')}")

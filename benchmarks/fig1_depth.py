@@ -6,8 +6,8 @@ Quick: pendulum, units=32, layers in {1, 2, 4}, sharpness at depth 1 vs 4.
 
 The sweep runs on the vmapped fleet driver (``repro.rl.Sweep``): each
 depth is its own compiled shape, so ``from_grid`` partitions the grid
-into one sub-fleet per depth with the seed replicas batched inside it
-(device replay + scan chunks — the fleet requirements). ``--sequential``
+into one sub-fleet per depth with the seed replicas batched inside it.
+``--sequential``
 keeps the legacy one-``Experiment``-at-a-time loop over the SAME specs
 for A/B (rows suffixed ``_seq`` so the committed fleet rows survive).
 """
@@ -15,18 +15,13 @@ from __future__ import annotations
 
 from benchmarks.common import bench_run, fleet_rows, make_spec
 
-# the fleet driver's spec requirements, shared by both modes so the
-# --sequential A/B compares schedules, not replay backends
-FLEET_OVERRIDES = dict(replay_backend="device", loop="scan")
-
 
 def run(scale: str = "quick", sequential: bool = False):
     layers = [1, 2, 4] if scale == "quick" else [1, 2, 4, 8, 16]
     units = 32 if scale == "quick" else 256
     env = "pendulum" if scale == "quick" else "cartpole_swingup"
     seeds = 5 if scale == "paper" else 1
-    base = make_spec(scale, "fig1-depth", env=env, num_units=units,
-                     **FLEET_OVERRIDES)
+    base = make_spec(scale, "fig1-depth", env=env, num_units=units)
     if sequential:
         return [bench_run(f"fig1_depth_L{nl}_seq",
                           base.override(num_layers=nl),

@@ -8,14 +8,13 @@ seed replicas vmapped inside it. ``--sequential`` keeps the legacy loop
 over the same specs for A/B (rows suffixed ``_seq``).
 """
 from benchmarks.common import bench_run, fleet_rows, make_spec
-from benchmarks.fig1_depth import FLEET_OVERRIDES
 
 
 def run(scale: str = "quick", sequential: bool = False):
     units = [16, 64, 256] if scale == "quick" else [128, 256, 512, 1024,
                                                     2048]
     seeds = 5 if scale == "paper" else 1
-    base = make_spec(scale, "fig3-width", **FLEET_OVERRIDES)
+    base = make_spec(scale, "fig3-width")
     if sequential:
         return [bench_run(f"fig3_width_U{nu}_seq",
                           base.override(num_units=nu),

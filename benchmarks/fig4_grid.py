@@ -9,14 +9,13 @@ full cartesian grid into one sub-fleet per cell, seeds vmapped inside.
 (rows suffixed ``_seq``).
 """
 from benchmarks.common import bench_run, fleet_rows, make_spec
-from benchmarks.fig1_depth import FLEET_OVERRIDES
 
 
 def run(scale: str = "quick", sequential: bool = False):
     units = [32, 128] if scale == "quick" else [128, 256, 512, 1024, 2048]
     layers = [1, 4] if scale == "quick" else [1, 2, 4, 8, 16]
     seeds = 5 if scale == "paper" else 1
-    base = make_spec(scale, "fig4-grid", **FLEET_OVERRIDES)
+    base = make_spec(scale, "fig4-grid")
     if sequential:
         return [bench_run(f"fig4_grid_U{nu}_L{nl}_seq",
                           base.override(num_units=nu, num_layers=nl),

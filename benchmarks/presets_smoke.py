@@ -20,7 +20,6 @@ def run(scale: str = "quick"):
         assert type(spec).from_dict(spec.to_dict()) == spec
         rows.append({"name": f"preset_build_{name}",
                      "us_per_call": 1e6 * (time.time() - t0),
-                     "derived": spec.execution.loop,
                      "env": spec.env, "algo": spec.algo})
     return rows
 

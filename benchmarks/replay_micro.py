@@ -1,12 +1,12 @@
-"""Replay microbenchmark: host (NumPy sum-tree) vs device (repro.replay).
+"""Replay microbenchmark: the device replay (repro.replay).
 
-Per-op wall time for the Ape-X replay hot loop — ``add_batch`` /
-``sample`` / ``update_priorities`` — swept over capacity 2^14..2^20
-("quick" trims the sweep for CPU CI). ``derived`` reports sampled
-transitions per second. The device backend is timed through its jitted
-functional ops with the XLA tree (CPU-honest; the Pallas kernel is timed at
-the smallest capacity only — interpret mode is a correctness harness, not a
-speed proxy — and its TPU story is the roofline's).
+Per-op wall time for the Ape-X replay hot loop — ``add`` / ``sample`` /
+``update`` — swept over capacity 2^14..2^20 ("quick" trims the sweep for
+CPU CI). ``derived`` reports sampled transitions per second. The replay is
+timed through its jitted functional ops with the XLA tree (CPU-honest; the
+Pallas kernel is timed at the smallest capacity only — interpret mode is a
+correctness harness, not a speed proxy — and its TPU story is the
+roofline's).
 
   PYTHONPATH=src python -m benchmarks.replay_micro
 """
@@ -34,20 +34,6 @@ def _time(fn, reps):
     for _ in range(reps):
         fn()
     return 1e6 * (time.time() - t0) / reps
-
-
-def _bench_host(capacity, reps):
-    from repro.rl.replay import PrioritizedReplay
-    buf = PrioritizedReplay(capacity, 8, 2)
-    batch = _mk_batch(BATCH, seed=1)
-    buf.add_batch(_mk_batch(capacity // 2, seed=0))   # half-full, realistic
-    rng = np.random.default_rng(2)
-    t_add = _time(lambda: buf.add_batch(batch), reps)
-    t_sample = _time(lambda: buf.sample(BATCH, rng), reps)
-    _, idx, _ = buf.sample(BATCH, rng)
-    pr = np.abs(rng.normal(size=BATCH))
-    t_upd = _time(lambda: buf.update_priorities(idx, pr), reps)
-    return t_add, t_sample, t_upd
 
 
 def _bench_device(capacity, reps, backend):
@@ -93,7 +79,6 @@ def run(scale: str = "quick"):
                      "add_us": round(t_add), "update_us": round(t_upd)})
 
     for cap in caps:
-        emit("host", cap, *_bench_host(cap, reps))
         emit("device", cap, *_bench_device(cap, reps, "xla"))
     # Pallas interpret mode: smallest capacity only (correctness harness)
     emit("device_pallas", caps[0], *_bench_device(caps[0], max(reps // 5, 1),

@@ -7,15 +7,15 @@ Every stored row is stamped with a ``host`` fingerprint (platform, CPU
 count, jax version/backend) and ``recorded_at``, so a ratio in the
 committed JSON is traceable to the box and build that produced it — and
 mixed-provenance files are detectable. Ratio rows additionally embed their
-same-run baseline (see ``loop_fusion.both_steps_per_sec``: the baseline
-reps are interleaved with the measured ones on the same box, so the ratio
-is never an artifact of when each side was measured).
+same-run baseline (its reps are interleaved with the measured ones on the
+same box, so the ratio is never an artifact of when each side was
+measured).
 
   PYTHONPATH=src python -m benchmarks.run [--scale quick|paper] [--only fig5]
 
 ``--smoke`` is the CI bitrot guard: the preset registry resolves and builds
-every paper scenario (presets_smoke), then one-rep runs of the kernel/loop
-benchmarks (dense_stack, loop_fusion) — failures fatal instead of
+every paper scenario (presets_smoke), then one-rep runs of the kernel and
+serving benchmarks (dense_stack, serve_policy) — failures fatal instead of
 swallowed, results written to experiments/bench_smoke.json.
 """
 import argparse
@@ -56,16 +56,15 @@ MODULES = [
     "benchmarks.kernels_micro",
     "benchmarks.replay_micro",
     "benchmarks.dense_stack",
-    "benchmarks.loop_fusion",
     "benchmarks.sweep_fleet",
     "benchmarks.serve_policy",
 ]
 
 # presets_smoke resolves every paper scenario through the preset registry
 # (construct + validate + build the Experiment, no jit) before the
-# kernel/loop one-rep runs
+# kernel/serving one-rep runs
 SMOKE_MODULES = ["benchmarks.presets_smoke", "benchmarks.dense_stack",
-                 "benchmarks.loop_fusion", "benchmarks.serve_policy"]
+                 "benchmarks.serve_policy"]
 
 
 def _merge_write(path: Path, rows) -> None:
@@ -108,7 +107,8 @@ def main() -> None:
             failed.append(mod_name)
             continue
         for r in rows:
-            print(f"{r['name']},{r['us_per_call']:.0f},{r['derived']}")
+            print(f"{r['name']},{r['us_per_call']:.0f},"
+                  f"{r.get('derived', '')}")
         all_rows.extend(rows)
     stamp = {"host": host_fingerprint(),
              "recorded_at": datetime.datetime.now(

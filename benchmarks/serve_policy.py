@@ -11,11 +11,11 @@ the compile cache stays pinned to the slot set (no per-batch-size
 recompiles; the engine pads to power-of-two slots).
 
 Both legs run through the SAME server code path and their reps are
-INTERLEAVED with min-of-reps taken (the loop_fusion pattern), so the
-reported ratio is never an artifact of when each leg was measured. The
-first pass of each leg compiles + warms and is excluded. The hot-swap row
-pushes a new param generation mid-traffic and asserts the engine's
-contract: zero dropped responses, zero mixed generations, swap landed.
+INTERLEAVED with min-of-reps taken, so the reported ratio is never an
+artifact of when each leg was measured. The first pass of each leg
+compiles + warms and is excluded. The hot-swap row pushes a new param
+generation mid-traffic and asserts the engine's contract: zero dropped
+responses, zero mixed generations, swap landed.
 
   PYTHONPATH=src python -m benchmarks.serve_policy
 """

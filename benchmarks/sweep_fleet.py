@@ -21,9 +21,9 @@ updates and an all-live fleet pays nearly nothing for freeze support.
 
 Timed end to end through the PUBLIC surfaces (``Fleet.run`` vs
 ``Experiment.run``, host epilogue work included) with the reps of both
-legs INTERLEAVED and min-of-reps taken, the loop_fusion pattern — the
-reported ratio is never an artifact of when each leg was measured. The
-first pass of each leg compiles + warms and is excluded.
+legs INTERLEAVED and min-of-reps taken — the reported ratio is never an
+artifact of when each leg was measured. The first pass of each leg
+compiles + warms and is excluded.
 
   PYTHONPATH=src python -m benchmarks.sweep_fleet
 """

@@ -21,7 +21,6 @@ not device time):
                     compiled chunk, a TPU plane in the trace, and the
                     sum-tree kernels equal to the XLA reference on the
                     trained tree.
-* ``train_default`` the repo defaults (python loop, host replay).
 * ``parity``        one SAC update on the TPU and on the host CPU, same
                     inputs; relative gaps in losses and grad norms within
                     the bound of the matmul precision the program runs at.
@@ -62,7 +61,6 @@ WIDTH = 2048                  # widest fig3 width (benchmarks/fig3_width.py)
 STEPS = 300                   # total_steps 1_000_000
 WARMUP = 256                  # warmup_steps 10_000: one batch of rows
 EVAL_EVERY = 100              # eval.every 10_000: three eval points
-DEFAULT_STEPS = 4             # repo-default driver: per-step dispatch
 FUSED_STEPS = 20
 # widest trunk whose fused-stack training chunk compiles for v5e: at U=1024
 # the stack's backward exceeds the kernel's 16 MiB scoped-VMEM limit
@@ -110,12 +108,10 @@ def _line(name: str, **fields) -> None:
 
 def paper_spec(**overrides):
     """table1-ours at the paper budget and U=2048, cut to the smoke budget,
-    on the TPU path (scan loop, device replay, Pallas sum-tree)."""
+    on the TPU path (Pallas sum-tree)."""
     from benchmarks.common import make_spec
     return make_spec("paper", "table1-ours", **{
-        "network.num_units": WIDTH,
-        "replay.backend": "device", "replay.kernel": "pallas",
-        "execution.loop": "scan",
+        "network.num_units": WIDTH, "replay.kernel": "pallas",
         "execution.total_steps": STEPS, "execution.warmup_steps": WARMUP,
         "eval.every": EVAL_EVERY, **overrides})
 
@@ -233,31 +229,6 @@ def phase_train_tpu(ctx) -> None:
           returns=[float(r) for r in res.returns],
           params=res.param_count, trace=status, trace_planes=planes,
           sumtree_sample_equal=same_leaf, sumtree_set_rel_gap=set_gap)
-
-
-def phase_train_default(ctx) -> None:
-    import jax
-    import numpy as np
-    from repro.rl import Experiment
-
-    spec = ctx["spec"].override(**{
-        "replay.backend": "host", "replay.kernel": "xla",
-        "execution.loop": "python",
-        "execution.total_steps": DEFAULT_STEPS,
-        "eval.every": DEFAULT_STEPS})
-    exp = Experiment.from_spec(spec)
-    t0 = time.time()
-    res = exp.run(DEFAULT_STEPS)
-    jax.block_until_ready(exp._ls)
-    wall_s = time.time() - t0
-    _require(len(res.returns) == 1 and np.isfinite(res.returns).all(),
-             res.returns)
-    _require(_finite_tree(exp._ls.agent["params"]), "non-finite params")
-    _line("train_default", ok=True, loop=spec.execution.loop,
-          replay=spec.replay.backend, steps=int(exp.step),
-          host_wall_s_with_compile=wall_s,
-          returns=[float(r) for r in res.returns],
-          dispatches=int(res.metrics["host_dispatches"]))
 
 
 def _update_inputs(exp):
@@ -486,8 +457,7 @@ def phase_mesh4(ctx) -> None:
     _require(gap < 400, (r4.returns, r1.returns))
 
 
-PHASES = (phase_train_tpu, phase_train_default, phase_parity, phase_fused,
-          phase_serve)
+PHASES = (phase_train_tpu, phase_parity, phase_fused, phase_serve)
 
 
 def run_phases(phases, ctx) -> list:

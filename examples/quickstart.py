@@ -10,7 +10,7 @@ Built on the layered experiment API: the ``quickstart`` preset plus
 with optional checkpoint/resume through the run handle.
 
     PYTHONPATH=src python examples/quickstart.py [--steps 2000]
-        [--override network.num_units=256] [--override replay.backend=device]
+        [--override network.num_units=256] [--override replay.n_step=3]
         [--ckpt run.npz] [--resume run.npz]
 
 Diagnosing instability: pass ``--log-dir runs/a`` to stream per-step
@@ -106,7 +106,7 @@ def main():
     ap.add_argument("--override", action="append", default=[],
                     metavar="KEY=VALUE",
                     help="spec override, e.g. network.num_layers=4 or "
-                         "replay_backend=device (repeatable)")
+                         "replay_kernel=pallas (repeatable)")
     ap.add_argument("--ckpt", default="", help="save the run handle here")
     ap.add_argument("--resume", default="",
                     help="restore a --ckpt checkpoint and keep training")

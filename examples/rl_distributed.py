@@ -3,14 +3,12 @@
 Runs Full / w/o Ape-X / w/o OFENet / w/o DenseNet / original-SAC on the same
 env+budget and prints the Fig.-10-style comparison table.
 
-Variants build from the ``rl-distributed`` preset (device-resident replay +
-scan superstep by default — the production path) through the layered spec
+Variants build from the ``rl-distributed`` preset through the layered spec
 API. Any spec field is reachable with ``--override key=value`` (repeatable;
-dotted paths or legacy flat aliases), replacing the old grown flag list:
+dotted paths or flat aliases):
 
     PYTHONPATH=src python examples/rl_distributed.py [--steps 800]
-        [--override replay.backend=host] [--override replay.kernel=pallas]
-        [--override execution.loop=python] [--override replay.n_step=3]
+        [--override replay.kernel=pallas] [--override replay.n_step=3]
         [--override network.block_backend=fused]
 
 Telemetry rides the same overrides: ``--override obs.enabled=true
@@ -40,7 +38,7 @@ def main():
     ap.add_argument("--env", default="pendulum")
     ap.add_argument("--override", action="append", default=[],
                     metavar="KEY=VALUE",
-                    help="spec override, e.g. replay.backend=host or "
+                    help="spec override, e.g. replay.kernel=pallas or "
                          "n_step=3 (repeatable)")
     args = ap.parse_args()
     enable_compile_cache()
@@ -49,9 +47,9 @@ def main():
     base = presets.get("rl-distributed").override(
         env=args.env, total_steps=args.steps,
         eval_every=max(args.steps // 2, 1), **overrides)
-    r, x, n = base.replay, base.execution, base.network
-    print(f"replay backend: {r.backend} ({r.kernel}), loop={x.loop}, "
-          f"n_step={r.n_step}, blocks={n.block_backend}")
+    r, n = base.replay, base.network
+    print(f"replay kernel: {r.kernel}, n_step={r.n_step}, "
+          f"blocks={n.block_backend}")
     print(f"{'variant':<14}{'max return':>12}{'params':>12}")
     for name, ov in VARIANTS.items():
         res = Experiment.from_spec(base.override(**ov)).run(eval_at_end=True)

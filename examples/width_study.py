@@ -7,7 +7,7 @@ has its own parameter shapes, so here that is one fleet per row — a seed
 battery per row would batch inside each fleet for free; try ``seeds=5``).
 
     PYTHONPATH=src python examples/width_study.py [--steps 400] [--seeds 1]
-        [--override execution.loop=scan]
+        [--override network.activation=relu]
 """
 import argparse
 
@@ -26,7 +26,6 @@ def main():
     base = presets.get("fig4-grid").override(
         n_env=1, total_steps=args.steps, warmup_steps=300,
         eval_every=max(args.steps // 2, 1),
-        replay_backend="device", loop="scan",
         **parse_overrides(args.override))
     grid = [("deep (6x32)", dict(num_layers=6, num_units=32)),
             ("base (2x32)", dict(num_layers=2, num_units=32)),

@@ -76,7 +76,6 @@ def run_sanitizers(preset: str = "smoke", *,
     from repro.rl.experiment import Experiment
 
     spec = presets.get(preset).override(
-        loop="scan", replay_backend="device",
         # srank on: its epilogue fetch is part of the guarded surface
         srank_every=presets.get(preset).eval.every,
         **{"obs.enabled": False, "guard.enabled": False})
@@ -140,7 +139,7 @@ def run_sanitizers(preset: str = "smoke", *,
             f"checkify flagged one superstep: {e}",
             "a NaN or out-of-bounds index inside the superstep is a "
             "training-pathology bug (the class the srank/guard machinery "
-            "watches for) — bisect with checkify on the python driver"))
+            "watches for) — bisect with checkify over the superstep's ops"))
     return findings
 
 

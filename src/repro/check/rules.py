@@ -15,8 +15,8 @@ entry points (``jit``, ``vmap``, ``pmap``, ``grad``, ``lax.scan`` /
 ``fori_loop`` / ``while_loop`` / ``cond`` / ``switch``, ``pallas_call``,
 ``shard_map``, ``eval_shape``, ``checkify``, ``custom_vjp``...), including
 through ``functools.partial``, or when it is called or referenced from the
-body of an already-traced same-module function (the injectable-ops pattern
-``Trainer._device_step(ls, collect_add, sample, ...)``).
+body of an already-traced same-module function (``Trainer._superstep``
+calling ``self._op_sample``).
 """
 from __future__ import annotations
 

@@ -223,14 +223,6 @@ class Monitor:
                 self._spike_hist.append(v)
         return out
 
-    def check_scalars(self, step: int, scalars: Mapping[str, float],
-                      member: Optional[int] = None) -> List[Violation]:
-        """Single-step variant (python loop driver): the same checks over
-        one row of scalars."""
-        return self.check_stream(
-            step - 1, {k: np.asarray([v]) for k, v in scalars.items()},
-            member=member)
-
     def check_params(self, step: int, params,
                      member: Optional[int] = None) -> List[Violation]:
         if not self.spec.check_params:

@@ -8,7 +8,7 @@ Reads ``<run_dir>/metrics.jsonl`` (the JSONL sink's output; see
 * throughput        — gradient steps/sec from the per-chunk timing events
 * grad norms        — first/last/peak per network (actor/critics/OFENet),
                       plus the update/param-norm ratios
-* staleness         — replay priority-staleness trajectory (device backend)
+* staleness         — replay priority-staleness trajectory
 * losses / TD error — trajectory stats
 * eval              — best/final return
 * instability flags — spikes (value > SPIKE_FACTOR x run median), non-finite

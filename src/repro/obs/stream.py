@@ -1,6 +1,6 @@
 """The in-loop metric stream: chunk flush, downsampling, counters, events.
 
-``ObsRun`` is the per-``Experiment`` observability engine. The scan driver
+``ObsRun`` is the per-``Experiment`` observability engine. The driver
 hands it whole chunks at a time — the stacked per-step scalar stream the
 chunk emitted as scan outputs (``Trainer.chunk_fn``'s ``out["stream"]``, one
 ``(n_steps,)`` array per scalar) — and ``flush_chunk`` downsamples against
@@ -8,8 +8,7 @@ ABSOLUTE step numbers (``step % log_every == 0``) before pushing rows to the
 buffered async writer. Because downsampling happens on the host from a
 stream the scan body always emits in full, the body compiles identically for
 every ``log_every`` and every chunk length: obs knobs can never perturb the
-PR-5 bitwise-resume contract. The python driver calls ``log_train`` per
-step instead; both drivers produce the identical row set.
+bitwise-resume contract.
 
 Rows (see ``repro.obs`` for the schema) flow through one ``BufferedWriter``
 fanning out to the spec's sinks; ``drain()`` empties the queue and is called
@@ -114,17 +113,6 @@ class ObsRun:
             self.last_train_step = int(rows[-1]["step"])
             self.rows_written += len(rows)
             self._emit(rows)
-
-    def log_train(self, step: int, scalars: Mapping[str, float]) -> None:
-        """Per-step entry point (python driver). Applies the same absolute
-        ``log_every`` filter as ``flush_chunk``."""
-        if not self.enabled or step % self.log_every:
-            return
-        row: Row = {"kind": "train", "step": int(step)}
-        row.update({k: float(v) for k, v in scalars.items()})
-        self.last_train_step = int(step)
-        self.rows_written += 1
-        self._emit([row])
 
     # ------------------------------------------------------- eval + events
     def log_eval(self, step: int, ret: float,

@@ -18,8 +18,8 @@ Implementations:
   thread fanning rows out to the wrapped sinks, so file I/O never sits on
   the training thread between chunk dispatches. ``drain()`` blocks until
   the queue is empty and re-raises any sink error — ``Experiment.save``
-  calls it right after ``jax.effects_barrier()``, the same barrier that
-  already drains the host-replay io_callbacks.
+  calls it right after ``jax.block_until_ready`` and
+  ``jax.effects_barrier()``, so the metric files match the checkpoint.
 """
 from __future__ import annotations
 

@@ -1,9 +1,7 @@
 """Device-resident sharded prioritized replay (Ape-X on the mesh).
 
-The host replay in ``rl/replay.py`` round-trips every learner step:
-actor batches device -> host (NumPy sum-tree add), sampled batches
-host -> device (learner update). This subsystem keeps the whole
-``collect -> add -> sample -> update_priorities`` loop on device:
+The training replay: the whole ``collect -> add -> sample ->
+update_priorities`` loop stays on device:
 
 * ``store``   — pure-JAX circular transition store: a pytree of
   preallocated ``(capacity, ...)`` arrays plus an int32 cursor; functional
@@ -12,22 +10,21 @@ host -> device (learner update). This subsystem keeps the whole
   ``repro.kernels.replay_tree.ops`` to either the fused Pallas descent
   kernel (``backend="pallas"``, interpret mode on CPU) or the XLA
   scatter/gather reference (``backend="xla"``, the CPU-fast default).
-  Semantics mirror the host ``PrioritizedReplay`` (stratified proportional
-  sampling, alpha/beta exponents, batch-max-normalized IS weights), which
-  stays in-tree as the parity oracle.
+  Semantics mirror the NumPy ``rl/replay.py`` buffer (stratified
+  proportional sampling, alpha/beta exponents, batch-max-normalized IS
+  weights), which stays in-tree as the tests' parity oracle.
 * ``sharded`` — one replay shard per mesh ``data``-axis slice, matching the
   sharded actor pool in ``rl/apex.py``: adds are shard-local, sampling is
   stratified across shards, IS weights renormalize via an on-mesh pmax, and
   ``collect_and_add_sharded`` fuses actor stepping with the replay add into
   a single ``shard_map`` program.
 
-Backend switch: ``ExperimentSpec`` ``replay.backend = "host" | "device"``,
-``replay.kernel = "xla" | "pallas"``. With ``"device"`` the runner threads
-the functional ``ReplayState`` through jitted add/sample/update steps — no
-per-step host<->device transfer of the replay store (see
+Kernel switch: ``ExperimentSpec`` ``replay.kernel = "xla" | "pallas"``.
+The runner threads the functional ``ReplayState`` through its superstep —
+no per-step host<->device transfer of the replay store (see
 examples/rl_distributed.py and benchmarks/replay_micro.py). Because every
-operation is pure, the runner's ``loop="scan"`` superstep carries the whole
-ReplayState through ``jax.lax.scan`` — and on a mesh
+operation is pure, the superstep carries the whole ReplayState through
+``jax.lax.scan`` — and on a mesh
 (``execution.mesh_shards=n``) through ``collect_and_add_sharded`` /
 ``sharded_replay_sample`` inside the same scanned chunk. ``store.nstep_*``
 roll n-step returns (``replay.n_step=3``) on device in the add path;

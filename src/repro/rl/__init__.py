@@ -7,8 +7,7 @@ The run surface is a validated spec tree plus a resumable handle
 
     from repro.rl import Experiment, presets
 
-    spec = presets.get("quickstart").override(num_units=256,
-                                              **{"replay.backend": "device"})
+    spec = presets.get("quickstart").override(num_units=256)
     exp = Experiment.from_spec(spec)       # builds everything, no jit yet
     exp.run(5_000)                         # advance; eval at spec cadence
     exp.save("run.npz")                    # full state + spec metadata
@@ -18,25 +17,22 @@ The run surface is a validated spec tree plus a resumable handle
 
 Save/restore is bitwise-reproducible at ANY step, not just at eval-chunk
 boundaries: interrupted and uninterrupted schedules produce identical eval
-returns, final params and replay state under both loop drivers and both
-replay backends (the scan driver's chunk is one ``lax.scan`` with the last
-step's outputs carried through the scan carry, so the superstep compiles
-identically however the run is chunked — see ``Experiment`` /
-``Trainer.chunk_fn``).
+returns, final params and replay state (a chunk is one ``lax.scan`` with
+the last step's outputs carried through the scan carry, so the superstep
+compiles identically however the run is chunked — see ``Experiment`` /
+``Trainer.chunk_fn``). Every run trains through that scanned superstep
+over the device replay (``repro.replay``).
 
 Spec tree (``ExperimentSpec``): ``env``/``algo`` plus six sub-specs —
 ``network`` (width/depth/connectivity/activation/``block_backend``),
-``ofenet`` (decoupled representation), ``replay``
-(host|device backend, xla|pallas kernel, capacity, PER, n-step),
-``execution`` (python|scan loop driver, mesh shards, batch, steps, Ape-X
-actor pool, seed), ``eval`` (cadence, episodes, srank) and ``obs``
-(telemetry, below). Invalid values and unsupported combinations (e.g.
-``replay.kernel="pallas"`` on the host backend, the fused block kernel with
-OFENet batch norm, mesh sharding on the host replay) raise ``SpecError`` at
-construction; valid-but-degraded combinations (python loop on a mesh) raise
-``SpecWarning``. Specs serialize via ``to_dict``/``from_dict`` (unknown
-keys skipped with a warning — forward compat) and sweep via ``override``
-with dotted paths or the flat legacy aliases.
+``ofenet`` (decoupled representation), ``replay`` (xla|pallas sum-tree
+kernel, capacity, PER, n-step), ``execution`` (mesh shards, batch, steps,
+Ape-X actor pool, seed), ``eval`` (cadence, episodes, srank) and ``obs``
+(telemetry, below). Invalid values and unsupported combinations (e.g. the
+fused block kernel with OFENet batch norm) raise ``SpecError`` at
+construction. Specs serialize via ``to_dict``/``from_dict`` (unknown keys
+skipped with a ``SpecWarning`` — forward compat) and sweep via
+``override`` with dotted paths or the flat aliases.
 
 Observability (``repro.obs``, configured by ``ObsSpec``)::
 
@@ -47,9 +43,9 @@ Observability (``repro.obs``, configured by ``ObsSpec``)::
     Experiment.from_spec(spec).run()
     # then: python -m repro.obs.report runs/exp0
 
-The scan driver streams every per-step scalar training metric out of the
-jitted chunk as stacked scan outputs and flushes them in the chunk epilogue;
-the python driver logs per step. Rows flow through an async buffered writer
+The driver streams every per-step scalar training metric out of the
+jitted chunk as stacked scan outputs and flushes them in the chunk
+epilogue. Rows flow through an async buffered writer
 into the configured sinks (``jsonl`` / ``csv`` / ``memory``).
 ``obs.grad_norms`` adds per-network gradient-norm + update-ratio taps;
 ``obs.trace=N`` captures a ``jax.profiler`` trace of the first N chunks.
@@ -75,9 +71,7 @@ grid and partitions it into per-compiled-shape sub-fleets (reported via
     sweep.run()                          # 2 compiled programs, 10 members
     best = max(sweep.results(), key=lambda m: m.result.max_return)
 
-Fleets default to the device replay backend (the host io_callback replay
-cannot batch under vmap — building a host-backend fleet raises
-``SpecError``), evaluate per member at the same absolute steps as solo
+Fleets evaluate per member at the same absolute steps as solo
 runs, support per-member early stopping (``stop_at_return`` /
 ``set_done`` freeze a member's carry without perturbing neighbors or
 recompiling), checkpoint through the same ``ckpt.py`` path
@@ -190,15 +184,11 @@ Presets (``repro.rl.presets``): every paper scenario by name —
 ``fig13-activation``, ``table1-ours``, ``table1-orig`` — plus
 ``quickstart``, ``rl-distributed`` and ``smoke``. All ``benchmarks/fig*.py``
 and ``examples/`` build through ``presets.get(name).override(...)``.
-
-The flat ``RunConfig`` + one-shot ``run_training`` are gone: their
-deprecation period ended and both names now raise ``RuntimeError`` with a
-porting recipe (every flat field still works as an ``override`` alias).
 """
 from repro.rl.envs import ENVS, EnvSpec, eval_returns, make_env, \
     rollout_return
 from repro.rl.policy import Policy
-from repro.rl.runner import RunConfig, RunResult, run_training
+from repro.rl.runner import RunResult
 from repro.rl.experiment import (EvalSpec, ExecutionSpec, Experiment,
                                  ExperimentSpec, NetworkSpec, ObsSpec,
                                  OFENetSpec, ReplaySpec, SpecError,

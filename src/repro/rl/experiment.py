@@ -1,15 +1,15 @@
 """Layered experiment API: typed spec tree + resumable ``Experiment`` handle.
 
-The run surface for the paper's method is a tree of small, validated specs
-instead of the flat 22-field ``RunConfig``:
+The run surface for the paper's method is a tree of small, validated specs:
 
     ExperimentSpec
     ├── env / algo            task + algorithm ("pendulum", "sac" | "td3")
     ├── network:   NetworkSpec    width / depth / connectivity / activation /
     │                             block_backend  (Figs. 1/3/4/5/13)
     ├── ofenet:    OFENetSpec     decoupled representation  (Figs. 6/7)
-    ├── replay:    ReplaySpec     backend / kernel / capacity / PER / n-step
-    ├── execution: ExecutionSpec  loop driver / mesh shards / batch / steps /
+    ├── replay:    ReplaySpec     sum-tree kernel / capacity / PER /
+    │                             n-step
+    ├── execution: ExecutionSpec  mesh shards / batch / steps /
     │                             Ape-X actor pool / seed
     ├── eval:      EvalSpec       eval cadence + srank instrumentation
     ├── obs:       ObsSpec        in-loop telemetry: metric stream cadence,
@@ -19,20 +19,19 @@ instead of the flat 22-field ``RunConfig``:
 
 Every field is choice-checked at construction and unsupported combinations
 are rejected with actionable messages (``SpecError``) instead of failing
-deep inside jit — e.g. ``replay.kernel="pallas"`` with the host NumPy
-replay, or the fused block kernel with OFENet batch norm. Combinations that
-merely *degrade* (a python-loop driver on a sharded mesh) emit a
-``SpecWarning``. ``to_dict``/``from_dict`` serialize the tree (unknown keys
-are ignored with a warning — forward compat for older binaries reading newer
-checkpoints), and ``override(**kwargs)`` builds sweep variants from dotted
-paths (``{"network.num_units": 512}``) or the flat legacy aliases
-(``num_units=512``).
+deep inside jit — e.g. the fused block kernel with OFENet batch norm.
+``execution.loop`` and ``replay.backend`` each have one legal value
+(``"scan"``, ``"device"``): every run trains through the scanned superstep
+over the device replay. ``to_dict``/``from_dict`` serialize the tree
+(unknown keys are ignored with a ``SpecWarning`` — forward compat for older
+binaries reading newer checkpoints), and ``override(**kwargs)`` builds sweep
+variants from dotted paths (``{"network.num_units": 512}``) or the flat
+aliases (``num_units=512``).
 
-On top of the spec sits the resumable ``Experiment`` handle, replacing the
-one-shot blocking ``run_training``:
+On top of the spec sits the resumable ``Experiment`` handle:
 
     exp = Experiment.from_spec(spec)        # builds the Trainer, no jit yet
-    exp.run(10_000)                         # advance (either loop driver)
+    exp.run(10_000)                         # advance in scanned chunks
     exp.save("run.npz")                     # full state -> checkpoint/ckpt.py
     ...
     exp = Experiment.restore("run.npz")     # spec read back from metadata
@@ -40,24 +39,20 @@ one-shot blocking ``run_training``:
     rows = list(exp.metrics())              # RunResult-style eval rows
 
 ``save`` round-trips the complete training state — agent/actors/replay
-pytree (typed PRNG keys stored as raw key data), the host replay buffer's
-NumPy arrays + sum tree + RNG state when ``replay.backend="host"``, and the
-accumulated eval history — through ``repro.checkpoint.ckpt`` with the spec
-serialized into the checkpoint metadata, so a checkpoint is self-describing.
+pytree (typed PRNG keys stored as raw key data) and the accumulated eval
+history — through ``repro.checkpoint.ckpt`` with the spec serialized into
+the checkpoint metadata, so a checkpoint is self-describing.
 
 With ``obs.enabled`` the run additionally streams per-step training
-diagnostics (``repro.obs``): the scan driver flushes each chunk's stacked
-scalar stream to the configured sinks, the python driver logs per step, and
-``save`` drains the async writer next to the same effects barrier that
-drains the host-replay callbacks. Enabling obs changes training outputs
-bitwise not at all (tests/test_obs.py).
+diagnostics (``repro.obs``): the driver flushes each chunk's stacked scalar
+stream to the configured sinks, and ``save`` drains the async writer next
+to the effects barrier. Enabling obs changes training outputs bitwise not
+at all (tests/test_obs.py).
 
 Paper scenarios are named in ``repro.rl.presets``. Grids of spec variants
 (a figure's sweep, a seed battery) can run as ONE vmapped device program
 per compiled shape through ``repro.rl.sweep`` (``Sweep.from_grid`` /
-``Fleet``) instead of a sequential loop of ``Experiment``s. The flat
-``RunConfig`` / ``run_training`` surface is gone — both names now raise
-with a porting message (``repro.rl.runner``).
+``Fleet``) instead of a sequential loop of ``Experiment``s.
 """
 from __future__ import annotations
 
@@ -74,7 +69,6 @@ import numpy as np
 from repro.checkpoint import ckpt
 from repro.guard.monitor import GuardSpec, GuardViolation, Monitor
 from repro.core.blocks import BLOCK_BACKENDS, CONNECTIVITIES
-from repro.core.effective_rank import effective_rank
 from repro.core.ofenet import OFENetConfig
 from repro.common import ACTIVATIONS
 from repro.obs.stream import ObsRun
@@ -84,9 +78,12 @@ from repro.rl.envs import ENVS
 from repro.rl.runner import RunResult, Trainer, TrainLoopState
 
 ALGOS = ("sac", "td3")
-REPLAY_BACKENDS = ("host", "device")
+REPLAY_BACKENDS = ("device",)
 REPLAY_KERNELS = ("xla", "pallas")
-LOOPS = ("python", "scan")
+LOOPS = ("scan",)
+# why execution.loop / replay.backend accept one value each
+_ONE_DRIVER = ("the scan driver over the device replay replaced the "
+               "per-step python loop and the host NumPy replay")
 
 _SPEC_VERSION = 1
 
@@ -96,13 +93,13 @@ class SpecError(ValueError):
 
 
 class SpecWarning(UserWarning):
-    """Valid-but-degraded combination, or forward-compat key skipping."""
+    """Forward-compat key skipping: a newer writer's fields were ignored."""
 
 
-def _choice(spec: str, field: str, value, choices) -> None:
+def _choice(spec: str, field: str, value, choices, why: str = "") -> None:
     if value not in choices:
         raise SpecError(f"{spec}.{field}={value!r} is not one of "
-                        f"{tuple(choices)}")
+                        f"{tuple(choices)}" + (f": {why}" if why else ""))
 
 
 def _positive(spec: str, field: str, value, minimum: int = 1) -> None:
@@ -182,15 +179,16 @@ class OFENetSpec:
 
 @dataclasses.dataclass(frozen=True)
 class ReplaySpec:
-    """Replay storage + sampling (PR-1 device subsystem or host NumPy)."""
-    backend: str = "host"              # host | device
-    kernel: str = "xla"                # device sum-tree impl: xla | pallas
+    """Device replay storage + sampling (``repro.replay``)."""
+    backend: str = "device"            # the one backend (see _ONE_DRIVER)
+    kernel: str = "xla"                # sum-tree impl: xla | pallas
     capacity: int = 100_000
     prioritized: bool = True
     n_step: int = 1                    # Ape-X n-step returns
 
     def __post_init__(self):
-        _choice("replay", "backend", self.backend, REPLAY_BACKENDS)
+        _choice("replay", "backend", self.backend, REPLAY_BACKENDS,
+                _ONE_DRIVER)
         _choice("replay", "kernel", self.kernel, REPLAY_KERNELS)
         _boolean("replay", "prioritized", self.prioritized)
         _positive("replay", "capacity", self.capacity)
@@ -199,8 +197,8 @@ class ReplaySpec:
 
 @dataclasses.dataclass(frozen=True)
 class ExecutionSpec:
-    """How the training loop runs: driver, sharding, batch, actor pool."""
-    loop: str = "python"               # python (per-step dispatch) | scan
+    """How the training loop runs: sharding, batch, actor pool."""
+    loop: str = "scan"                 # the one driver (see _ONE_DRIVER)
     mesh_shards: int = 0               # >0: actors+replay on a data mesh
     batch_size: int = 256
     total_steps: int = 2000            # default budget for run(steps=None)
@@ -211,7 +209,7 @@ class ExecutionSpec:
     seed: int = 0
 
     def __post_init__(self):
-        _choice("execution", "loop", self.loop, LOOPS)
+        _choice("execution", "loop", self.loop, LOOPS, _ONE_DRIVER)
         _boolean("execution", "distributed", self.distributed)
         _positive("execution", "mesh_shards", self.mesh_shards, minimum=0)
         _positive("execution", "batch_size", self.batch_size)
@@ -281,8 +279,7 @@ class ObsSpec:
                 f"write into (obs.log_dir='runs/exp0').")
 
 
-# flat legacy-RunConfig field -> dotted spec path, used by override() and
-# the RunConfig shim so sweeps read the same in old and new code
+# flat field name -> dotted spec path, used by override()
 _ALIASES: Dict[str, str] = {
     "num_units": "network.num_units",
     "num_layers": "network.num_layers",
@@ -345,19 +342,7 @@ class ExperimentSpec:
 
     def _validate_combos(self):
         r, x = self.replay, self.execution
-        if r.kernel == "pallas" and r.backend != "device":
-            raise SpecError(
-                "replay.kernel='pallas' requires replay.backend='device': "
-                "the host replay is a NumPy sum-tree and has no Pallas "
-                "path (the flat RunConfig used to ignore this silently). "
-                "Set replay.backend='device' or replay.kernel='xla'.")
         if x.mesh_shards > 0:
-            if r.backend != "device":
-                raise SpecError(
-                    "execution.mesh_shards>0 requires "
-                    "replay.backend='device': mesh-sharded replay lives in "
-                    "repro.replay (sharded collect+add / cross-shard "
-                    "sample); the host NumPy buffer cannot be sharded.")
             for fname, val in (("n_actors", x.n_actors),
                                ("batch_size", x.batch_size),
                                ("capacity", r.capacity)):
@@ -366,13 +351,6 @@ class ExperimentSpec:
                         f"execution.mesh_shards={x.mesh_shards} must divide "
                         f"{fname}={val} (actors, batch and replay rows are "
                         f"split evenly across the mesh 'data' axis)")
-            if x.loop == "python":
-                warnings.warn(
-                    "execution.mesh_shards>0 with execution.loop='python' "
-                    "degrades quietly: the per-step dispatch loop forfeits "
-                    "the scan superstep's dispatch amortization on the "
-                    "mesh. Prefer execution.loop='scan'.", SpecWarning,
-                    stacklevel=3)
         if (self.guard.enabled and self.guard.srank_collapse > 0
                 and not self.eval.srank_every):
             raise SpecError(
@@ -421,9 +399,9 @@ class ExperimentSpec:
     def override(self, **kwargs) -> "ExperimentSpec":
         """A new validated spec with the given fields replaced.
 
-        Keys are dotted spec paths (``{"replay.backend": "device"}`` via
-        ``override(**mapping)``) or the flat legacy RunConfig aliases
-        (``num_units=512``, ``replay_backend="device"``); top-level
+        Keys are dotted spec paths (``{"replay.kernel": "pallas"}`` via
+        ``override(**mapping)``) or the flat aliases (``num_units=512``,
+        ``replay_kernel="pallas"``); top-level
         ``env``/``algo`` work as-is. Unknown keys raise ``SpecError`` —
         sweeps should fail loudly, not drop a knob."""
         d = self.to_dict()
@@ -441,11 +419,10 @@ class ExperimentSpec:
                     or isinstance(node[parts[-1]], dict):
                 raise SpecError(
                     f"unknown override key {key!r}; use a dotted spec path "
-                    f"(e.g. 'network.num_units'), a legacy alias "
+                    f"(e.g. 'network.num_units'), a flat alias "
                     f"({sorted(_ALIASES)}), or 'env'/'algo'")
             node[parts[-1]] = value
-        # d round-trips through from_dict (no unknown keys possible), so the
-        # only warnings that can fire here are genuine combo warnings
+        # d round-trips through from_dict (no unknown keys possible)
         return ExperimentSpec.from_dict(d)
 
     def ofenet_config(self, obs_dim: int, act_dim: int) -> OFENetConfig:
@@ -462,7 +439,7 @@ def parse_overrides(pairs: List[str]) -> Dict[str, Any]:
 
     Values parse as Python literals when possible (``True``, ``3``,
     ``0.5``), with shell-style ``true``/``false`` accepted as bools, and
-    fall back to strings (``device``, ``scan``) — bool-typed spec fields
+    fall back to strings (``pallas``, ``relu``) — bool-typed spec fields
     reject leftover strings at validation, so a typo'd flag can never run
     the wrong experiment silently."""
     out: Dict[str, Any] = {}
@@ -470,7 +447,7 @@ def parse_overrides(pairs: List[str]) -> Dict[str, Any]:
         key, sep, val = s.partition("=")
         if not sep or not key:
             raise SpecError(f"override {s!r} must be key=value "
-                            f"(e.g. replay.backend=device)")
+                            f"(e.g. replay.kernel=pallas)")
         if val.lower() in ("true", "false"):
             out[key] = val.lower() == "true"
             continue
@@ -508,7 +485,7 @@ class Experiment:
     ``from_spec`` builds the Trainer (env, agent ops, replay wiring) without
     executing any jitted program; the first ``run``/``save`` initializes
     state (agent init + random-policy warmup). ``run(steps)`` advances in
-    chunks under either loop driver, evaluating at absolute multiples of
+    scanned chunks (``Trainer.chunk_fn``), evaluating at absolute multiples of
     ``spec.eval.every`` — so ``run(N); save; restore; run(M)`` is seed-exact
     with an uninterrupted ``run(N + M)``. ``save``/``restore`` round-trip
     the complete training state through ``repro.checkpoint.ckpt`` with the
@@ -516,14 +493,11 @@ class Experiment:
 
     Bitwise-reproducibility contract: ``run(N); save; restore; run(M)`` is
     bitwise-equal (eval returns, final params, replay state) to an
-    uninterrupted ``run(N + M)`` at ANY split point, under BOTH loop drivers
-    and both replay backends. The python driver never re-chunks, and the
-    scan driver's chunk is one ``lax.scan`` over all its supersteps with the
-    last step's metrics/batch carried through the scan carry
-    (``Trainer.chunk_fn``) — the superstep only ever compiles as the scan
-    body, so re-chunking the same step sequence executes the identical
-    compiled computation per step. (The two DRIVERS still differ from each
-    other at fusion level, ~1e-4 — the guarantee is per-driver.)
+    uninterrupted ``run(N + M)`` at ANY split point. A chunk is one
+    ``lax.scan`` over all its supersteps with the last step's metrics/batch
+    carried through the scan carry (``Trainer.chunk_fn``) — the superstep
+    only ever compiles as the scan body, so re-chunking the same step
+    sequence executes the identical compiled computation per step.
     """
 
     def __init__(self, spec: ExperimentSpec, *, mesh=None):
@@ -551,8 +525,7 @@ class Experiment:
     @classmethod
     def restore(cls, path: str, *, mesh=None) -> "Experiment":
         """Rebuild a handle from ``save`` output: spec from the checkpoint
-        metadata, every state leaf (and the host replay buffer + RNG, if
-        any) from the array payload."""
+        metadata, every state leaf from the array payload."""
         meta = ckpt.load_metadata(path)
         if meta is None or "spec" not in meta:
             raise FileNotFoundError(
@@ -587,21 +560,6 @@ class Experiment:
         # dispatch accounting continues across the resume so
         # metrics["host_dispatches"] matches an uninterrupted run
         self.trainer.dispatches = int(st.get("dispatches", 0))
-
-        buf = self.trainer.buffer
-        if buf is not None:
-            inner = getattr(buf, "_inner", buf)
-            with np.load(path) as raw:
-                for k in inner.data:
-                    inner.data[k][...] = raw[f"host/data/{k}"]
-                inner.tree.tree[...] = raw["host/tree"]
-            b = st["buffer"]
-            inner.ptr = int(b["ptr"])
-            inner.count = int(b["count"])
-            inner.max_priority = float(b["max_priority"])
-            rng = np.random.default_rng()
-            rng.bit_generator.state = b["rng_state"]
-            self.trainer.rng = rng
         self._obs.load_state(st.get("obs"))
 
     # ------------------------------------------------------------ running
@@ -618,15 +576,14 @@ class Experiment:
         ``srank_every``, independent of where ``run`` calls start and stop —
         that is what makes interrupted and uninterrupted schedules
         seed-exact. ``eval_at_end`` additionally evaluates at the final step
-        of THIS call (the legacy ``run_training`` contract; it consumes a
-        PRNG split, so only bitwise-reproducible by runs stopping at the
-        same step). ``keep_last`` retains the final sampled batch +
-        priorities (loss-landscape tooling). Returns the cumulative
-        ``RunResult`` snapshot.
+        of THIS call (it consumes a PRNG split, so it is only
+        bitwise-reproducible by runs stopping at the same step).
+        ``keep_last`` retains the final sampled batch + priorities
+        (loss-landscape tooling). Returns the cumulative ``RunResult``
+        snapshot.
 
-        With ``spec.obs.enabled`` the call also streams diagnostics: the
-        scan driver flushes each chunk's stacked scalar stream + a per-chunk
-        timing event, the python driver logs per step; both land in the
+        With ``spec.obs.enabled`` the call also streams diagnostics: each
+        chunk's stacked scalar stream + a per-chunk timing event land in the
         sinks via the async writer, which is drained before returning."""
         t0 = time.time()
         x, ev, obs = self.spec.execution, self.spec.eval, self._obs
@@ -637,127 +594,70 @@ class Experiment:
         trainer, ls = self.trainer, self._ls
         start, end = self.step, self.step + steps
 
-        if x.loop == "scan":
-            # chunks stop at every eval point AND (when instrumented) every
-            # srank point, so the scan driver records the exact same
-            # returns/sranks steps as the per-step python loop. Chunking is
-            # pure scheduling: the superstep only ever compiles as the scan
-            # body, so any chunking of the same step sequence is bitwise-
-            # identical (Trainer.chunk_fn).
-            step = start
-            mon = self._monitor
-            while step < end:
-                stops = [(step // eval_every + 1) * eval_every, end]
-                if srank_every:
-                    stops.append((step // srank_every + 1) * srank_every)
-                stop = min(stops)
-                do_eval = (stop % eval_every == 0
-                           or (eval_at_end and stop == end))
-                do_srank = bool(srank_every) and stop % srank_every == 0
-                want_last = keep_last and stop == end
-                snap = (self._guard_snapshot(ls, step)
-                        if mon is not None else None)
-                obs.trace.begin()
-                tc = time.time()
-                with annotate("repro.chunk_dispatch"):
-                    ls, out = trainer.chunk_fn(stop - step, do_eval,
-                                               do_srank)(ls)
-                hstream = None
-                if "stream" in out:
-                    with annotate("repro.obs.flush"):
-                        hstream = jax.device_get(out["stream"])
-                if mon is not None:
-                    with annotate("repro.guard.check"):
-                        viol = mon.check_stream(step, hstream) \
-                            if hstream is not None else []
-                        viol += mon.check_params(stop, ls.agent["params"])
-                    if viol:
-                        obs.trace.end()
-                        ls, step = self._guard_recover(viol, snap)
-                        continue
-                if hstream is not None:
-                    with annotate("repro.obs.flush"):
-                        obs.flush_chunk(step, hstream)
-                    obs.chunk_event(step, stop, time.time() - tc)
-                obs.trace.end()
-                step = stop
-                if do_srank:
-                    # explicit device_get: the chunk epilogue is the ONE
-                    # sanctioned host<->device barrier in the scan driver,
-                    # so the steady state stays clean under
-                    # jax.transfer_guard("disallow") (repro.check dynamic)
-                    srank = int(jax.device_get(out["srank"]))
-                    self.sranks.append(srank)
-                    obs.log_event("srank", step=step, srank=srank)
-                    if mon is not None:
-                        with annotate("repro.guard.check"):
-                            viol = mon.check_srank(step, self.sranks)
-                        if viol:
-                            ls, step = self._guard_recover(viol, snap)
-                            continue
-                if want_last:
-                    self._last_batch, self._last_priorities = out["last"]
-                if do_eval:
-                    with annotate("repro.eval_readback"):
-                        ev_ret, scal = jax.device_get((out["eval"],
-                                                       out["scal"]))
-                    self._record_eval(
-                        step, float(np.mean(ev_ret)),
-                        {k: float(v) for k, v in scal.items()}, progress)
-        else:
-            metrics = batch = None
-            mon = self._monitor
-            step = start
+        # chunks stop at every eval point AND (when instrumented) every
+        # srank point. Chunking is pure scheduling: the superstep only ever
+        # compiles as the scan body, so any chunking of the same step
+        # sequence is bitwise-identical (Trainer.chunk_fn).
+        step = start
+        mon = self._monitor
+        while step < end:
+            stops = [(step // eval_every + 1) * eval_every, end]
+            if srank_every:
+                stops.append((step // srank_every + 1) * srank_every)
+            stop = min(stops)
+            do_eval = (stop % eval_every == 0
+                       or (eval_at_end and stop == end))
+            do_srank = bool(srank_every) and stop % srank_every == 0
+            want_last = keep_last and stop == end
             snap = (self._guard_snapshot(ls, step)
                     if mon is not None else None)
-            while step < end:
-                step += 1
-                ls, metrics, batch = trainer.py_step(ls)
+            obs.trace.begin()
+            tc = time.time()
+            with annotate("repro.chunk_dispatch"):
+                ls, out = trainer.chunk_fn(stop - step, do_eval,
+                                           do_srank)(ls)
+            hstream = None
+            if "stream" in out:
+                with annotate("repro.obs.flush"):
+                    hstream = jax.device_get(out["stream"])
+            if mon is not None:
+                with annotate("repro.guard.check"):
+                    viol = mon.check_stream(step, hstream) \
+                        if hstream is not None else []
+                    viol += mon.check_params(stop, ls.agent["params"])
+                if viol:
+                    obs.trace.end()
+                    ls, step = self._guard_recover(viol, snap)
+                    continue
+            if hstream is not None:
+                with annotate("repro.obs.flush"):
+                    obs.flush_chunk(step, hstream)
+                obs.chunk_event(step, stop, time.time() - tc)
+            obs.trace.end()
+            step = stop
+            if do_srank:
+                # explicit device_get: the chunk epilogue is the ONE
+                # sanctioned host<->device barrier in the driver, so
+                # the steady state stays clean under
+                # jax.transfer_guard("disallow") (repro.check dynamic)
+                srank = int(jax.device_get(out["srank"]))
+                self.sranks.append(srank)
+                obs.log_event("srank", step=step, srank=srank)
                 if mon is not None:
-                    # per-step checks: the python driver is the debug path,
-                    # so it pays a per-step host sync for exact detection
-                    viol = mon.check_scalars(
-                        step, {k: float(np.asarray(v))
-                               for k, v in metrics.items()
-                               if np.ndim(v) == 0})
-                    viol += mon.check_params(step, ls.agent["params"])
+                    with annotate("repro.guard.check"):
+                        viol = mon.check_srank(step, self.sranks)
                     if viol:
                         ls, step = self._guard_recover(viol, snap)
-                        snap = self._guard_snapshot(ls, step)
                         continue
-                if obs.enabled and step % obs.log_every == 0:
-                    obs.log_train(step, {k: float(np.asarray(v))
-                                         for k, v in metrics.items()
-                                         if np.asarray(v).ndim == 0})
-                if srank_every and step % srank_every == 0:
-                    srank = int(effective_rank(metrics["q_features"]))
-                    self.sranks.append(srank)
-                    obs.log_event("srank", step=step, srank=srank)
-                    if mon is not None:
-                        viol = mon.check_srank(step, self.sranks)
-                        if viol:
-                            ls, step = self._guard_recover(viol, snap)
-                            snap = self._guard_snapshot(ls, step)
-                            continue
-                if (step % eval_every == 0
-                        or (eval_at_end and step == end)):
-                    key, ke = jax.random.split(ls.key)
-                    ls = ls._replace(key=key)
-                    rets = np.asarray(trainer.eval_j(ls.agent["params"],
-                                                     ke))
-                    self._record_eval(
-                        step, float(rets.mean()),
-                        {k: float(np.asarray(v).mean())
-                         for k, v in metrics.items()
-                         if np.asarray(v).ndim == 0}, progress)
-                    if mon is not None:
-                        # eval points are the segment boundaries the skip
-                        # policy rewinds to
-                        snap = self._guard_snapshot(ls, step)
-            if keep_last and metrics is not None:
-                self._last_batch = batch
-                self._last_priorities = metrics["priorities"]
-
+            if want_last:
+                self._last_batch, self._last_priorities = out["last"]
+            if do_eval:
+                with annotate("repro.eval_readback"):
+                    ev_ret, scal = jax.device_get((out["eval"],
+                                                   out["scal"]))
+                self._record_eval(
+                    step, float(np.mean(ev_ret)),
+                    {k: float(v) for k, v in scal.items()}, progress)
         self._ls, self.step = ls, end
         wall = time.time() - t0
         self._wall += wall
@@ -792,26 +692,11 @@ class Experiment:
     def _guard_snapshot(self, ls: TrainLoopState, step: int) -> dict:
         """Pre-segment snapshot for the skip policy. Device state is free —
         JAX arrays are immutable, holding the old ``ls`` reference IS the
-        snapshot — so only the host-mutated pieces cost anything: history
-        list lengths, the obs cursor, and (host replay + skip policy only)
-        a copy of the buffer/sum-tree/RNG, taken behind an effects barrier
-        so in-flight io_callbacks can't tear it."""
-        snap = {"ls": ls, "step": step, "obs": self._obs.state(),
+        snapshot — so only the host-side pieces are recorded: history list
+        lengths and the obs cursor."""
+        return {"ls": ls, "step": step, "obs": self._obs.state(),
                 "hist": (len(self.returns), len(self.eval_steps),
                          len(self.sranks), len(self._rows))}
-        buf = self.trainer.buffer
-        if buf is not None and self._monitor.spec.policy == "skip":
-            jax.block_until_ready(ls)
-            jax.effects_barrier()
-            inner = getattr(buf, "_inner", buf)
-            snap["buffer"] = {
-                "data": {k: v.copy() for k, v in inner.data.items()},
-                "tree": inner.tree.tree.copy(),
-                "ptr": inner.ptr, "count": inner.count,
-                "max_priority": inner.max_priority,
-                "rng_state": self.trainer.rng.bit_generator.state,
-            }
-        return snap
 
     def _guard_recover(self, violations, snap) -> Tuple[TrainLoopState, int]:
         """Apply ``guard.policy`` to a non-empty violation list; returns the
@@ -848,18 +733,6 @@ class Experiment:
         r0, e0, s0, w0 = snap["hist"]
         del self.returns[r0:], self.eval_steps[e0:]
         del self.sranks[s0:], self._rows[w0:]
-        if "buffer" in snap:
-            inner = getattr(self.trainer.buffer, "_inner",
-                            self.trainer.buffer)
-            b = snap["buffer"]
-            for k in inner.data:
-                inner.data[k][...] = b["data"][k]
-            inner.tree.tree[...] = b["tree"]
-            inner.ptr, inner.count = b["ptr"], b["count"]
-            inner.max_priority = b["max_priority"]
-            rng = np.random.default_rng()
-            rng.bit_generator.state = b["rng_state"]
-            self.trainer.rng = rng
         self._obs.load_state(snap["obs"])
         ls = snap["ls"]
         ls = ls._replace(key=jax.random.fold_in(ls.key, ordinal))
@@ -917,8 +790,7 @@ class Experiment:
         return Policy.from_experiment(self)
 
     def result(self, *, include_state: bool = False) -> RunResult:
-        """The cumulative RunResult snapshot (shape-compatible with the
-        legacy ``run_training`` return)."""
+        """The cumulative RunResult snapshot."""
         metrics_out = dict(self._last_metrics,
                            host_dispatches=float(self.trainer.dispatches))
         return RunResult(
@@ -937,22 +809,16 @@ class Experiment:
         """Write the full training state + spec metadata to ``path``.
 
         Layout: one npz holding the ``TrainLoopState`` pytree (typed PRNG
-        keys as raw key data) and, for the host replay backend, the buffer
-        arrays + float64 sum tree under ``host/``; a sibling
-        ``.meta.json`` with the serialized spec, eval history, and the
-        host buffer's scalar cursor/RNG state."""
+        keys as raw key data); a sibling ``.meta.json`` with the serialized
+        spec and eval history."""
         self._ensure_init()
-        # A mid-period stop can leave the last scan chunk still executing
-        # (its outputs were never fetched), with the host replay's ordered
-        # io_callbacks still mutating the buffer/RNG on the runtime thread —
-        # snapshotting now would tear the checkpoint (buffer arrays final,
-        # RNG mid-chunk). Drain the program AND its effects first; the obs
-        # writer queue drains at the same barrier so the metric files are
-        # consistent with the snapshot.
+        # A mid-period stop can leave the last chunk still executing (its
+        # outputs were never fetched). Drain the program AND any ordered
+        # effects first; the obs writer queue drains at the same barrier so
+        # the metric files are consistent with the snapshot.
         jax.block_until_ready(self._ls)
         jax.effects_barrier()
         self._obs.drain()
-        tree: Dict[str, Any] = {"loop": _unkey(self._ls)}
         state: Dict[str, Any] = {
             "step": self.step, "returns": self.returns,
             "eval_steps": self.eval_steps, "sranks": self.sranks,
@@ -962,16 +828,7 @@ class Experiment:
             "dispatches": int(self.trainer.dispatches),
             "obs": self._obs.state(),
         }
-        buf = self.trainer.buffer
-        if buf is not None:
-            inner = getattr(buf, "_inner", buf)
-            tree["host"] = {"data": inner.data, "tree": inner.tree.tree}
-            state["buffer"] = {
-                "ptr": inner.ptr, "count": inner.count,
-                "max_priority": inner.max_priority,
-                "rng_state": self.trainer.rng.bit_generator.state,
-            }
-        ckpt.save(path, tree,
+        ckpt.save(path, {"loop": _unkey(self._ls)},
                   metadata={"spec": self.spec.to_dict(), "experiment": state})
         self._obs.log_event("save", step=self.step, path=str(path))
         self._obs.drain()

@@ -3,7 +3,7 @@
 Every paper figure/table scenario is a named ``ExperimentSpec`` so drivers
 stop hand-building configs: ``presets.get("fig5-connectivity")`` returns the
 validated base spec for that scenario and sweeps derive variants with
-``.override(...)`` (dotted paths or legacy flat aliases). All presets carry
+``.override(...)`` (dotted paths or flat aliases). All presets carry
 the CPU-quick budget (runnable on a laptop); ``benchmarks/common.py`` scales
 them to the paper budget for real hardware, and every preset must build an
 ``Experiment`` without executing jit (enforced by a tier-1 test and
@@ -21,8 +21,8 @@ Names follow the paper artifacts: ``fig1-depth``, ``fig3-width``,
 ``fig4-grid``, ``fig5-connectivity``, ``fig6-ofenet``, ``fig8-distributed``,
 ``fig10-ablation``, ``fig13-activation``, ``table1-ours``, ``table1-orig``,
 plus the repo's own end-to-end scenarios ``quickstart``,
-``rl-distributed`` (device replay + scan superstep) and ``smoke`` (tiny CI
-dims). ``register`` adds project-local scenarios.
+``rl-distributed`` and ``smoke`` (tiny CI dims). ``register`` adds
+project-local scenarios.
 """
 from __future__ import annotations
 
@@ -83,14 +83,12 @@ _PRESETS: Dict[str, ExperimentSpec] = {
         algo="sac", num_units=128, num_layers=2, connectivity="densenet",
         use_ofenet=True, ofenet_units=32, ofenet_layers=2,
         distributed=True, n_core=2, n_env=16, total_steps=800,
-        warmup_steps=300, eval_every=400,
-        replay_backend="device", loop="scan"),
+        warmup_steps=300, eval_every=400),
     "smoke": _BASE.override(
         num_units=16, num_layers=1, use_ofenet=False, n_core=1, n_env=4,
         total_steps=12, warmup_steps=8, eval_every=6, eval_episodes=1,
         replay_capacity=256, batch_size=16),
-    # fleet-ready tiny scenario (device replay — the vmapped sweep driver's
-    # requirement) for CI fleet smoke + benchmarks/sweep_fleet.py
+    # fleet-ready tiny scenario for CI fleet smoke + benchmarks/sweep_fleet.py
     # dims sit in the op-overhead-bound regime where fleet batching pays:
     # uniform replay (the PER sum-tree's scatter writes are serial
     # per-element on CPU and scale linearly under vmap — see the
@@ -99,8 +97,7 @@ _PRESETS: Dict[str, ExperimentSpec] = {
     "fleet-smoke": _BASE.override(
         num_units=16, num_layers=1, use_ofenet=False, n_core=1, n_env=4,
         total_steps=64, warmup_steps=16, eval_every=32, eval_episodes=1,
-        replay_capacity=256, batch_size=8, prioritized=False,
-        replay_backend="device", loop="scan"),
+        replay_capacity=256, batch_size=8, prioritized=False),
 }
 
 

@@ -1,4 +1,6 @@
-"""Distributed prioritized experience replay (Schaul et al. 2016 / Ape-X).
+"""NumPy prioritized experience replay (Schaul et al. 2016 / Ape-X): the
+tests' oracle for the device replay (``repro.replay``), which is what
+training runs. Nothing in the training path imports this module.
 
 Host-side circular buffer with a vectorized NumPy sum-tree for O(log N)
 proportional sampling (stratified, as in the PER paper) and importance

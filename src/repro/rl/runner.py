@@ -2,9 +2,8 @@
 
 The typed entry point is ``repro.rl.experiment`` (``ExperimentSpec`` +
 resumable ``Experiment`` handle); this module keeps the ``Trainer`` engine
-they drive. ``Trainer`` consumes the spec tree natively (the flat
-``RunConfig``/``run_training`` surface is GONE — the former deprecation
-shims now raise with a porting hint). Every paper ablation is a spec field:
+they drive. ``Trainer`` consumes the spec tree natively. Every paper
+ablation is a spec field:
 
 * ``network.connectivity``   — mlp | resnet | densenet | d2rl   (Fig. 5)
 * ``network.num_units/_layers`` — width/depth study             (Figs. 1/3/4)
@@ -16,8 +15,7 @@ shims now raise with a porting hint). Every paper ablation is a spec field:
   (actor, twin critics, OFENet online/target) through the fused streaming
   DenseNet-stack kernel (``kernels/dense_block/stack.py``, custom VJP) so
   the scanned superstep trains through it; "jnp" is the concat loop
-* ``replay.backend``         — host (NumPy sum-tree) | device (repro.replay)
-  with ``replay.kernel`` picking the device sum-tree impl ("xla" | "pallas")
+* ``replay.kernel``          — the device sum-tree impl ("xla" | "pallas")
 * ``replay.n_step``          — Ape-X n-step returns (1 | 3), computed on
   device in the replay add path (repro.replay.store.nstep_push)
 * ``obs``                    — in-loop telemetry (``repro.obs``): when
@@ -29,38 +27,29 @@ shims now raise with a porting hint). Every paper ablation is a spec field:
   enabling obs does not change training outputs bitwise (tests/test_obs).
   ``obs.grad_norms`` threads ``grad_norms=True`` into the algorithm
   configs (sac/td3 grad-norm + update-ratio taps).
-* ``execution.loop``         — "python" | "scan":
 
-  The training loop is built around a functional ``TrainLoopState`` and a
-  pure superstep that fuses collect -> n-step -> add -> sample -> update ->
-  priority-refresh. ``loop="python"`` dispatches the superstep's pieces one
-  host call at a time (the debuggable legacy shape, ~5 dispatches per
-  gradient step). ``loop="scan"`` drives the SAME superstep with
-  ``jax.lax.scan`` in ``eval_every``-sized chunks — evaluation (a vmapped
-  rollout scan) folds into the same jitted chunk, so ``run_training`` issues
-  ``total_steps / eval_every + O(1)`` host dispatches total (plus
-  ``total_steps / srank_every`` when srank instrumentation is on: chunks
-  also stop at srank points so both drivers record identical steps; counted
-  in ``RunResult.metrics["host_dispatches"]``; throughput:
-  benchmarks/loop_fusion.py). A chunk is ONE scan over ALL its supersteps
-  with the last step's metrics/batch carried through the scan carry — the
-  superstep only ever compiles as the scan body, so any re-chunking of the
-  same step sequence is bitwise-identical (the resume-anywhere guarantee;
-  see ``Trainer.chunk_fn``). The host replay backend rides the scanned
-  superstep through ordered ``io_callback``s, so both backends are
-  seed-for-seed identical across ``loop=`` choices.
+The training loop is built around a functional ``TrainLoopState`` and a
+pure superstep that fuses collect -> n-step -> add -> sample -> update ->
+priority-refresh over the device replay (``repro.replay``). ``chunk_fn``
+drives it with ``jax.lax.scan`` in ``eval_every``-sized chunks — evaluation
+(a vmapped rollout scan) folds into the same jitted chunk, so a run issues
+``total_steps / eval_every + O(1)`` host dispatches total (plus
+``total_steps / srank_every`` when srank instrumentation is on: chunks also
+stop at srank points; counted in ``RunResult.metrics["host_dispatches"]``).
+A chunk is ONE scan over ALL its supersteps with the last step's
+metrics/batch carried through the scan carry — the superstep only ever
+compiles as the scan body, so any re-chunking of the same step sequence is
+bitwise-identical (the resume-anywhere guarantee; see ``Trainer.chunk_fn``).
 
-* ``execution.mesh_shards``  — >0 routes the superstep through the
-  mesh-sharded Ape-X wiring (``replay.collect_and_add_sharded`` +
-  ``sharded_replay_sample``): actors and replay shards live on the mesh
-  ``data`` axis (``launch.mesh.make_actor_mesh``), transitions never leave
-  their shard, and the learner consumes one coherent cross-shard batch.
-  Requires ``replay.backend="device"``.
+``execution.mesh_shards`` > 0 routes the superstep through the mesh-sharded
+Ape-X wiring (``replay.collect_and_add_sharded`` + ``sharded_replay_sample``):
+actors and replay shards live on the mesh ``data`` axis
+(``launch.mesh.make_actor_mesh``), transitions never leave their shard, and
+the learner consumes one coherent cross-shard batch.
 
 ``RunResult.metrics`` also surfaces the priority-staleness distribution of
 the last sampled batch (``staleness_mean/p50/max`` = learner step - add
-step) on the device backend; the host backend does not stamp rows, so the
-staleness keys are omitted there.
+step).
 """
 from __future__ import annotations
 
@@ -71,52 +60,27 @@ from typing import Any, Callable, Dict, List, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import io_callback
 
 from repro.common import tree_size
 from repro.core.effective_rank import effective_rank
 from repro.kernels import default_interpret
-from repro.obs.trace import annotate
 from repro.launch.mesh import make_actor_mesh, replay_shards
 from repro.replay import (DeviceReplayConfig, nstep_emit_flat, nstep_init,
                           replay_add, replay_init, replay_sample,
                           replay_update)
 from repro.replay import sharded as replay_sharded
 from repro.rl import apex, policy as policy_mod
-from repro.rl import replay as replay_mod, sac as sac_mod, td3 as td3_mod
+from repro.rl import sac as sac_mod, td3 as td3_mod
 from repro.rl.envs import EnvSpec, eval_returns, make_env
 
 _TRANSITION_FIELDS = ("obs", "act", "rew", "next_obs", "done")
-
-
-_REMOVED = (
-    "{name} was removed: the RunConfig deprecation period ended (it warned "
-    "since the ExperimentSpec API landed). Build a spec instead — the flat "
-    "field names still work as override aliases:\n"
-    "    from repro.rl import Experiment, ExperimentSpec\n"
-    "    spec = ExperimentSpec().override(num_units=256, "
-    "replay_backend='device', loop='scan')\n"
-    "    res = Experiment.from_spec(spec).run(spec.execution.total_steps)\n"
-    "or start from a repro.rl.presets entry.")
-
-
-class RunConfig:
-    """REMOVED — the flat config's deprecation warning is now an error."""
-
-    def __init__(self, *_a, **_k):
-        raise RuntimeError(_REMOVED.format(name="RunConfig"))
-
-
-def run_training(*_a, **_k):
-    """REMOVED — the one-shot shim's deprecation warning is now an error."""
-    raise RuntimeError(_REMOVED.format(name="run_training"))
 
 
 def _build(spec, env: EnvSpec):
     """Algorithm pieces for a (duck-typed) ``ExperimentSpec``: the algo
     config with OFENet/obs knobs threaded in, plus init/update fns. The
     act/eval policy functions live in ``repro.rl.policy`` (the unified
-    inference layer) — the former four duck-typed closures are gone."""
+    inference layer)."""
     acfg = policy_mod.algo_config(spec, env)
     if spec.algo == "sac":
         return acfg, sac_mod.sac_init, sac_mod.sac_update
@@ -150,7 +114,7 @@ class TrainLoopState(NamedTuple):
     agent: Any       # algorithm state: params / opt / step
     actors: Any      # vectorized EnvState of the Ape-X actor pool
     nstep: Any       # per-actor n-step rollback ring (None when n_step == 1)
-    replay: Any      # ReplayState (device/sharded) or an i32 token (host)
+    replay: Any      # ReplayState (device or mesh-sharded)
     key: jax.Array   # PRNG key, split once per superstep
     step: jax.Array  # completed learner steps (i32) — stamps replay adds
 
@@ -158,21 +122,16 @@ class TrainLoopState(NamedTuple):
 class Trainer:
     """Builds every jitted piece of the training loop once.
 
-    ``py_step`` runs one superstep as separate host dispatches (the legacy
-    debuggable loop); ``chunk_fn`` compiles ``n`` supersteps + optional
-    evaluation/srank into ONE program: a single ``jax.lax.scan`` whose carry
-    threads the last step's metrics/batch out, so the superstep compiles
-    identically for every chunk length (bitwise resume at any step). Both
-    drivers share the same pure ops and PRNG schedule, so they are
-    seed-for-seed interchangeable. ``dispatches`` counts host->device
-    program launches issued through this Trainer (the parity test's
-    traced-call counter).
+    ``chunk_fn`` compiles ``n`` supersteps + optional evaluation/srank into
+    ONE program: a single ``jax.lax.scan`` whose carry threads the last
+    step's metrics/batch out, so the superstep compiles identically for
+    every chunk length (bitwise resume at any step). ``dispatches`` counts
+    host->device program launches issued through this Trainer.
     """
 
     def __init__(self, spec, mesh=None):
         # consumes a typed ExperimentSpec natively (duck-typed by field
-        # access, so this module never imports repro.rl.experiment); the
-        # flat RunConfig view is gone
+        # access, so this module never imports repro.rl.experiment)
         self.spec = spec
         x, r = spec.execution, spec.replay
         # loop-hot scalars lifted off the spec tree once
@@ -205,11 +164,8 @@ class Trainer:
         if mesh is None and x.mesh_shards > 0:
             mesh = make_actor_mesh(x.mesh_shards)
         self.mesh = mesh
-        self.use_device = r.backend == "device"
+        shards = 1
         if mesh is not None:
-            if not self.use_device:
-                raise ValueError("mesh_shards requires replay.backend="
-                                 "'device'")
             shards = replay_shards(mesh)
             if (self.n_actors % shards or x.batch_size % shards
                     or r.capacity % shards):
@@ -217,45 +173,17 @@ class Trainer:
                     f"mesh_shards={shards} must divide n_actors="
                     f"{self.n_actors}, batch_size={x.batch_size} and "
                     f"replay_capacity={r.capacity}")
-        if not self.use_device and r.backend != "host":
-            raise ValueError(r.backend)
 
         self._train_policy = self.policy0.act_fn
         self._rand_policy = apex.random_policy(env.act_dim)
-
-        # ------------------------------------------------ replay backends
-        if self.use_device:
-            shards = replay_shards(mesh) if mesh is not None else 1
-            self.dcfg = DeviceReplayConfig(
-                capacity=r.capacity // shards, obs_dim=env.obs_dim,
-                act_dim=env.act_dim, uniform=not r.prioritized,
-                backend=r.kernel,
-                interpret=default_interpret(),
-                n_step=r.n_step)
-            self.buffer = None
-        else:
-            buf_cls = (replay_mod.PrioritizedReplay if r.prioritized
-                       else replay_mod.UniformReplay)
-            self.buffer = buf_cls(r.capacity, env.obs_dim,
-                                  env.act_dim, n_step=r.n_step)
-            self.rng = np.random.default_rng(x.seed)
-            self._host_fields = list(_TRANSITION_FIELDS)
-            if r.n_step > 1:
-                self._host_fields.append("disc")
-
-        # ------------------------------------------- jitted python-loop ops
-        w = self._count
-        self._update_j = w(jax.jit(self._op_update))
-        self.eval_j = w(jax.jit(lambda params, k: eval_returns(
+        self.dcfg = DeviceReplayConfig(
+            capacity=r.capacity // shards, obs_dim=env.obs_dim,
+            act_dim=env.act_dim, uniform=not r.prioritized,
+            backend=r.kernel,
+            interpret=default_interpret(),
+            n_step=r.n_step)
+        self.eval_j = self._count(jax.jit(lambda params, k: eval_returns(
             env, self.policy0.with_params(params), k, self.eval_episodes)))
-        if self.use_device:
-            self._collect_add_j = w(jax.jit(partial(
-                self._op_collect_add, self._train_policy, steps=1, drop=0)))
-            self._sample_j = w(jax.jit(self._op_sample))
-            self._update_prio_j = w(jax.jit(self._op_update_prio))
-        else:
-            self._collect_emit_j = w(jax.jit(partial(
-                self._collect_emit, self._train_policy, steps=1, drop=0)))
 
     # ------------------------------------------------------------- helpers
     def policy(self, params=None) -> "policy_mod.Policy":
@@ -306,23 +234,11 @@ class Trainer:
             tm(lambda x: place(x, data), ls.replay),
             place(ls.key, rep), place(ls.step, rep))
 
-    def _collect_emit(self, policy, params, actors, nstate, key, *,
-                      steps: int, drop: int):
-        """collect ``steps`` env steps and roll them through the n-step ring
-        (identity for n_step == 1); returns store-schema transition rows."""
-        with jax.named_scope("repro.collect"):
-            actors, trs = apex.collect(self.env, policy, params, actors,
-                                       steps, key)
-            if self.n_step == 1:
-                return actors, nstate, {k: trs[k]
-                                        for k in _TRANSITION_FIELDS}
-            nstate, flat = nstep_emit_flat(self.n_step, self.gamma, nstate,
-                                           trs, steps, drop)
-            return actors, nstate, flat
-
-    # ------------------------------------------------- device backend ops
+    # ------------------------------------------------- the superstep ops
     def _op_collect_add(self, policy, params, actors, nstate, rstate, key,
                         step, *, steps: int, drop: int):
+        """Collect ``steps`` env steps, roll them through the n-step ring
+        (identity for n_step == 1) and add the rows to the replay."""
         if self.mesh is not None:
             # one fused call collects and adds on each shard: the add's
             # scope covers both
@@ -336,8 +252,14 @@ class Trainer:
                     self.env, policy, self.mesh, self.dcfg, params, actors,
                     steps, key, rstate, step=step)
                 return actors, nstate, rstate
-        actors, nstate, flat = self._collect_emit(
-            policy, params, actors, nstate, key, steps=steps, drop=drop)
+        with jax.named_scope("repro.collect"):
+            actors, trs = apex.collect(self.env, policy, params, actors,
+                                       steps, key)
+            if self.n_step == 1:
+                flat = {k: trs[k] for k in _TRANSITION_FIELDS}
+            else:
+                nstate, flat = nstep_emit_flat(self.n_step, self.gamma,
+                                               nstate, trs, steps, drop)
         with jax.named_scope("repro.replay.add"):
             return actors, nstate, replay_add(self.dcfg, rstate, flat,
                                               step=step)
@@ -365,118 +287,30 @@ class Trainer:
                     self.dcfg, self.mesh, rstate, idx, priorities)
             return replay_update(self.dcfg, rstate, idx, priorities)
 
-    # --------------------------------------------- host backend callbacks
-    def _cb_add(self, *arrs):
-        with annotate("repro.replay.host_add"):
-            self.buffer.add_batch(dict(zip(self._host_fields,
-                                           [np.asarray(a) for a in arrs])))
-        return np.int32(0)
-
-    def _cb_sample(self):
-        with annotate("repro.replay.host_sample"):
-            batch, idx, weights = self.buffer.sample(self.batch_size,
-                                                     self.rng)
-        return (tuple(batch[f].astype(np.float32)
-                      for f in self._host_fields)
-                + (idx.astype(np.int32), weights.astype(np.float32)))
-
-    def _cb_update(self, idx, priorities):
-        with annotate("repro.replay.host_update_prio"):
-            self.buffer.update_priorities(np.asarray(idx),
-                                          np.asarray(priorities))
-        return np.int32(0)
-
-    def _host_sample_shapes(self):
-        env, bs = self.env, self.batch_size
-        dims = {"obs": (bs, env.obs_dim), "act": (bs, env.act_dim),
-                "rew": (bs,), "next_obs": (bs, env.obs_dim), "done": (bs,),
-                "disc": (bs,)}
-        return (tuple(jax.ShapeDtypeStruct(dims[f], jnp.float32)
-                      for f in self._host_fields)
-                + (jax.ShapeDtypeStruct((bs,), jnp.int32),
-                   jax.ShapeDtypeStruct((bs,), jnp.float32)))
-
     # ------------------------------------------------------ the superstep
-    def _device_step(self, ls, collect_add, sample, update, update_prio):
-        """The device-replay superstep over injectable ops — the scan body
-        passes the pure ops, the python driver their per-op jitted twins."""
+    def _superstep(self, ls: TrainLoopState):
+        """One pure collect->add->sample->update->refresh step — the scan
+        body. Each phase runs under its own ``jax.named_scope``
+        (``repro.collect``, ``repro.replay.add``, ``repro.replay.sample``,
+        ``repro.update``, ``repro.replay.refresh``): op metadata only, so a
+        profiler trace can put device time on phases while the program stays
+        the same."""
         key, kc, ks, ku = jax.random.split(ls.key, 4)
-        actors, nstate, rstate = collect_add(ls.agent["params"], ls.actors,
-                                             ls.nstep, ls.replay, kc,
-                                             ls.step)
-        batch, idx, staleness = sample(rstate, ks, ls.step)
-        agent, metrics = update(ls.agent, batch, ku)
-        rstate = update_prio(rstate, idx, metrics["priorities"])
-        return self._finish_step(ls, agent, actors, nstate, rstate, key,
-                                 staleness, metrics, batch)
-
-    def _finish_step(self, ls, agent, actors, nstate, rstate, key,
-                     staleness, metrics, batch):
-        """Shared superstep tail: staleness metrics + next TrainLoopState.
-        Keeping this single keeps the scan/python drivers seed-exact.
-        ``staleness=None`` (host replay: rows carry no add-step stamps)
-        omits the staleness keys instead of reporting a bogus sentinel."""
-        if staleness is not None:
-            with jax.named_scope("repro.replay.sample"):
-                metrics = dict(metrics,
-                               staleness_mean=staleness.mean(),
-                               staleness_p50=jnp.median(staleness),
-                               staleness_max=staleness.max())
+        actors, nstate, rstate = self._op_collect_add(
+            self._train_policy, ls.agent["params"], ls.actors, ls.nstep,
+            ls.replay, kc, ls.step, steps=1, drop=0)
+        batch, idx, staleness = self._op_sample(rstate, ks, ls.step)
+        agent, metrics = self._op_update(ls.agent, batch, ku)
+        rstate = self._op_update_prio(rstate, idx, metrics["priorities"])
+        with jax.named_scope("repro.replay.sample"):
+            metrics = dict(metrics,
+                           staleness_mean=staleness.mean(),
+                           staleness_p50=jnp.median(staleness),
+                           staleness_max=staleness.max())
         ls = TrainLoopState(agent, actors, nstate, rstate, key, ls.step + 1)
         return ls, metrics, batch
 
-    def _superstep(self, ls: TrainLoopState):
-        """One pure collect->add->sample->update->refresh step — the scan
-        body. Host replay rides along via ordered io_callbacks on the SAME
-        buffer/rng the python loop uses, so the two loops stay seed-exact.
-        Each phase runs under its own ``jax.named_scope`` (``repro.collect``,
-        ``repro.replay.add``, ``repro.replay.sample``, ``repro.update``,
-        ``repro.replay.refresh``): op metadata only, so a profiler trace
-        can put device time on phases while the program stays the same."""
-        if self.use_device:
-            return self._device_step(
-                ls,
-                partial(self._op_collect_add, self._train_policy, steps=1,
-                        drop=0),
-                self._op_sample, self._op_update, self._op_update_prio)
-        key, kc, ks, ku = jax.random.split(ls.key, 4)
-        actors, nstate, flat = self._collect_emit(
-            self._train_policy, ls.agent["params"], ls.actors, ls.nstep, kc,
-            steps=1, drop=0)
-        with jax.named_scope("repro.replay.add"):
-            io_callback(self._cb_add, jax.ShapeDtypeStruct((), jnp.int32),
-                        *[flat[f] for f in self._host_fields], ordered=True)
-        with jax.named_scope("repro.replay.sample"):
-            out = io_callback(self._cb_sample, self._host_sample_shapes(),
-                              ordered=True)
-        batch = dict(zip(self._host_fields, out))
-        idx, batch["weight"] = out[-2], out[-1]
-        agent, metrics = self._op_update(ls.agent, batch, ku)
-        with jax.named_scope("repro.replay.refresh"):
-            io_callback(self._cb_update, jax.ShapeDtypeStruct((), jnp.int32),
-                        idx, metrics["priorities"], ordered=True)
-        return self._finish_step(ls, agent, actors, nstate, ls.replay, key,
-                                 None, metrics, batch)
-
-    # ----------------------------------------------------------- drivers
-    def py_step(self, ls: TrainLoopState):
-        """One superstep as separate host dispatches (loop="python")."""
-        if self.use_device:
-            return self._device_step(ls, self._collect_add_j, self._sample_j,
-                                     self._update_j, self._update_prio_j)
-        key, kc, ks, ku = jax.random.split(ls.key, 4)
-        actors, nstate, flat = self._collect_emit_j(ls.agent["params"],
-                                                    ls.actors, ls.nstep, kc)
-        self.buffer.add_batch({k: np.asarray(v) for k, v in flat.items()})
-        batch_np, idx, weights = self.buffer.sample(self.batch_size,
-                                                    self.rng)
-        batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-        batch["weight"] = jnp.asarray(weights)
-        agent, metrics = self._update_j(ls.agent, batch, ku)
-        self.buffer.update_priorities(idx, np.asarray(metrics["priorities"]))
-        return self._finish_step(ls, agent, actors, nstate, ls.replay, key,
-                                 None, metrics, batch)
-
+    # ------------------------------------------------------------ driver
     def chunk_fn(self, n_steps: int, do_eval: bool,
                  do_srank: bool = False) -> Callable:
         """``n_steps`` supersteps (+ optional eval) as ONE jitted program.
@@ -491,10 +325,9 @@ class Trainer:
         srank and the final batch/priorities are computed from the carried
         outputs in the EPILOGUE, outside the scan — epilogue variation
         (eval/srank) cannot change how the body compiles, so ``do_eval`` /
-        ``do_srank`` only select what the chunk returns. ``want_last`` is
-        gone from the signature entirely (the last batch/priorities are
-        always available from the carry), shrinking the compile-cache key
-        space to (n_steps, do_eval, do_srank).
+        ``do_srank`` only select what the chunk returns; the last
+        batch/priorities always come from the carry, so the compile-cache
+        key space is (n_steps, do_eval, do_srank).
 
         With ``obs.enabled`` the scan body additionally stacks every scalar
         metric as a scan output — ``out["stream"]``, one ``(n_steps,)``
@@ -570,22 +403,18 @@ class Trainer:
         key, kw = jax.random.split(key)
         step0 = jnp.zeros((), jnp.int32)
 
-        if self.use_device:
-            if self.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                shards = replay_shards(self.mesh)
-                actors = jax.device_put(actors, NamedSharding(self.mesh,
-                                                              P("data")))
-                rstate = replay_sharded.sharded_replay_init(self.dcfg,
-                                                            self.mesh)
-                if self.n_step > 1:
-                    nstate = replay_sharded.sharded_nstep_init(
-                        self.mesh, self.n_step, self.n_actors // shards,
-                        env.obs_dim, env.act_dim)
-            else:
-                rstate = replay_init(self.dcfg)
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            shards = replay_shards(self.mesh)
+            actors = jax.device_put(actors, NamedSharding(self.mesh,
+                                                          P("data")))
+            rstate = replay_sharded.sharded_replay_init(self.dcfg, self.mesh)
+            if self.n_step > 1:
+                nstate = replay_sharded.sharded_nstep_init(
+                    self.mesh, self.n_step, self.n_actors // shards,
+                    env.obs_dim, env.act_dim)
         else:
-            rstate = jnp.zeros((), jnp.int32)   # order token placeholder
+            rstate = replay_init(self.dcfg)
         return TrainLoopState(agent, actors, nstate, rstate, key, step0), kw
 
     def init_template(self) -> TrainLoopState:
@@ -600,20 +429,9 @@ class Trainer:
         ls, kw = self._fresh_state()
         warm = max(self.warmup_steps // self.n_actors, 1, self.n_step)
         drop = self.n_step - 1
-        if self.use_device:
-            warm_j = self._count(jax.jit(partial(
-                self._op_collect_add, self._rand_policy, steps=warm,
-                drop=drop)))
-            actors, nstate, rstate = warm_j(ls.agent["params"], ls.actors,
-                                            ls.nstep, ls.replay, kw, ls.step)
-            ls = ls._replace(actors=actors, nstep=nstate, replay=rstate)
-        else:
-            warm_j = self._count(jax.jit(partial(
-                self._collect_emit, self._rand_policy, steps=warm,
-                drop=drop)))
-            actors, nstate, flat = warm_j(ls.agent["params"], ls.actors,
-                                          ls.nstep, kw)
-            self.buffer.add_batch({k: np.asarray(v)
-                                   for k, v in flat.items()})
-            ls = ls._replace(actors=actors, nstep=nstate)
+        warm_j = self._count(jax.jit(partial(
+            self._op_collect_add, self._rand_policy, steps=warm, drop=drop)))
+        actors, nstate, rstate = warm_j(ls.agent["params"], ls.actors,
+                                        ls.nstep, ls.replay, kw, ls.step)
+        ls = ls._replace(actors=actors, nstep=nstate, replay=rstate)
         return self._pin(ls, put=True)

@@ -27,12 +27,9 @@ Semantics
   ``Sweep.from_grid`` PARTITIONS the grid into per-point sub-fleets and
   reports the partition (``Sweep.partition``); building a ``Fleet`` from
   heterogeneous specs directly raises ``SpecError``.
-* **Device replay only.** The fleet default is ``replay.backend="device"``
-  (``from_grid`` upgrades host-backend bases with a ``SpecWarning``); the
-  host backend's ordered ``io_callback``s cannot batch under vmap and are
-  rejected with ``SpecError``, as are ``replay.kernel="pallas"``
-  (vmap-of-pallas is unpinned, see ROADMAP) and ``execution.mesh_shards``
-  (member-axis and mesh-axis composition is future work).
+* **XLA sum-tree, no mesh.** ``replay.kernel="pallas"`` (vmap-of-pallas
+  is unpinned, see ROADMAP) and ``execution.mesh_shards`` (member-axis and
+  mesh-axis composition is future work) are rejected with ``SpecError``.
 * **Scheduling exactly as today.** Eval/srank fire at absolute multiples of
   ``eval.every`` / ``eval.srank_every`` — the fleet chunk loop mirrors
   ``Experiment.run``'s stop computation, so member k of a fleet evaluates
@@ -67,8 +64,8 @@ Semantics
   and every row is tagged ``"member"`` (``repro.obs.report`` accepts the
   sweep directory and merges member streams).
 
-Member-vs-solo parity: a fleet member and a solo ``Experiment`` (device
-backend, scan driver) with the same spec+seed run the same ops in the same
+Member-vs-solo parity: a fleet member and a solo ``Experiment`` with the
+same spec+seed run the same ops in the same
 PRNG schedule, but vmap batches the member's matmuls with its neighbors',
 and batched reductions may reassociate floats — so parity is ALLCLOSE, not
 bitwise: eval returns and final params agree within ``SOLO_PARITY_RTOL`` /
@@ -88,7 +85,6 @@ import itertools
 import json
 import re
 import time
-import warnings
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
@@ -103,8 +99,8 @@ from repro.guard.store import DurableStore
 from repro.obs.stream import ObsRun
 from repro.obs.trace import annotate
 from repro.rl.envs import eval_returns
-from repro.rl.experiment import (ExperimentSpec, SpecError, SpecWarning,
-                                 _is_key, _rekey, _unkey)
+from repro.rl.experiment import (ExperimentSpec, SpecError, _is_key,
+                                 _rekey, _unkey)
 from repro.rl.runner import RunResult, Trainer, TrainLoopState
 
 # Documented member-vs-solo tolerance (see module docstring): the member's
@@ -184,14 +180,6 @@ class Fleet:
         if not specs:
             raise SpecError("Fleet needs at least one member spec")
         base = specs[0]
-        if base.replay.backend != "device":
-            raise SpecError(
-                "fleets require replay.backend='device': the host replay "
-                "rides the superstep through ordered io_callbacks, which "
-                "cannot batch under vmap (each member would need its own "
-                "host buffer and callback ordering). Override "
-                "replay_backend='device' — Sweep.from_grid does this "
-                "by default.")
         if base.replay.kernel != "xla":
             raise SpecError(
                 "fleets require replay.kernel='xla': vmap-of-pallas_call "
@@ -265,7 +253,7 @@ class Fleet:
     # --------------------------------------------------------- fleet state
     def _member_init(self, seed):
         """Solo init + warmup for one member (same op/PRNG schedule as
-        ``Trainer.init`` on the device backend) — vmapped over the member
+        ``Trainer.init``) — vmapped over the member
         seed vector so the whole fleet initializes as one program."""
         tr = self.trainer
         ls, kw = tr._fresh_state(seed)
@@ -864,20 +852,11 @@ class Sweep:
         (full cartesian product) or an explicit list of override dicts
         (irregular grids). ``seeds`` replicates every grid point with
         ``execution.seed`` = base seed + 0..seeds-1. Extra ``overrides``
-        apply to the base spec first. Host-replay bases are upgraded to
-        the device backend (the fleet default) with a ``SpecWarning``."""
+        apply to the base spec first."""
         from repro.rl import presets
         spec = presets.get(base) if isinstance(base, str) else base
         if overrides:
             spec = spec.override(**overrides)
-        if spec.replay.backend != "device":
-            warnings.warn(
-                "Sweep.from_grid: upgrading replay.backend to 'device' "
-                "(the fleet default — the host io_callback replay cannot "
-                "batch under vmap). Pass replay_backend='device' to "
-                "silence, or run host-backend specs solo.", SpecWarning,
-                stacklevel=2)
-            spec = spec.override(replay_backend="device")
         if isinstance(axis, Mapping):
             keys = list(axis)
             points = [dict(zip(keys, vals))

@@ -513,9 +513,8 @@ def test_chunk_signature_prediction_matches_trainer_cache():
     from repro.rl import presets
     from repro.rl.experiment import Experiment
 
-    spec = presets.get("smoke").override(
-        loop="scan", replay_backend="device", total_steps=10, eval_every=4,
-        srank_every=5)
+    spec = presets.get("smoke").override(total_steps=10, eval_every=4,
+                                         srank_every=5)
     exp = Experiment.from_spec(spec)
     exp.run()
     predicted = set(chunk_signatures(0, 10, 4, 5))

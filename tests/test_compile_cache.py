@@ -45,7 +45,7 @@ def test_cache_dir_defaults_to_fixed_checkout_path():
 
 
 ENTRY_POINTS = ["chip_smoke.py", "benchmarks/run.py",
-                "benchmarks/loop_fusion.py", "examples/quickstart.py",
+                "examples/quickstart.py",
                 "examples/rl_distributed.py", "examples/width_study.py",
                 "src/repro/launch/serve_policy.py",
                 "src/repro/guard/supervise.py", "src/repro/check/dynamic.py"]

@@ -1,5 +1,5 @@
-"""Parity tests: repro.replay (device) vs rl.replay (host oracle), plus the
-runner's ``replay_backend="device"`` end-to-end path and the mesh-sharded
+"""Parity tests: repro.replay (device) vs rl.replay (NumPy oracle), plus the
+runner's end-to-end path over the device replay and the mesh-sharded
 variant (4 fake CPU devices, subprocess like test_substrate)."""
 import os
 import subprocess
@@ -217,8 +217,7 @@ def test_runner_device_backend_trains(algo):
         env="pendulum", algo=algo, num_units=16, num_layers=1,
         use_ofenet=False, distributed=True, n_core=1, n_env=4,
         total_steps=10, warmup_steps=8, eval_every=10,
-        eval_episodes=1, replay_capacity=512, batch_size=16,
-        replay_backend="device")
+        eval_episodes=1, replay_capacity=512, batch_size=16)
     res = Experiment.from_spec(spec).run(eval_at_end=True)
     assert len(res.returns) == 1 and np.isfinite(res.returns[0])
 
@@ -229,7 +228,7 @@ def test_runner_device_pallas_matches_xla():
     base = dict(env="pendulum", num_units=16, num_layers=1, use_ofenet=False,
                 distributed=True, n_core=1, n_env=4, total_steps=8,
                 warmup_steps=8, eval_every=8, eval_episodes=1,
-                replay_capacity=256, batch_size=16, replay_backend="device")
+                replay_capacity=256, batch_size=16)
 
     def run(**kw):
         spec = ExperimentSpec().override(**base, **kw)

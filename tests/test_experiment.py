@@ -1,22 +1,20 @@
-"""Layered experiment API tests: spec-tree validation (invalid combos fail
-at construction; the removed RunConfig surface raises with a porting hint),
-to_dict/from_dict serialization incl. unknown-key forward compat, override
-semantics, checkpoint-metadata round-trip through checkpoint/ckpt.py, the
-preset registry building every paper scenario without jit, and save/restore
-resume parity: interrupted == uninterrupted BITWISE (returns, final params,
-replay state) at ANY split point — chunk-boundary and mid-period — for both
-loop drivers x both replay backends, plus a 4-fake-device mesh smoke at a
-non-boundary split."""
-import warnings
-
+"""Layered experiment API tests: spec-tree validation (invalid combos and
+the removed python-loop / host-replay paths fail at construction and on
+restore), to_dict/from_dict serialization incl. unknown-key forward compat,
+override semantics, checkpoint-metadata round-trip through
+checkpoint/ckpt.py, the preset registry building every paper scenario
+without jit, and save/restore resume parity: interrupted == uninterrupted
+BITWISE (returns, final params, replay state) at ANY split point —
+chunk-boundary and mid-period — for both sum-tree kernels x n-step 1 and 3,
+plus a 4-fake-device mesh smoke at a non-boundary split."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.checkpoint import ckpt
-from repro.rl import (Experiment, ExperimentSpec, RunConfig, SpecError,
-                      SpecWarning, parse_overrides, presets, run_training)
+from repro.rl import (Experiment, ExperimentSpec, SpecError, SpecWarning,
+                      parse_overrides, presets)
 from repro.rl.runner import Trainer
 
 _SMALL = dict(num_units=16, num_layers=1, use_ofenet=False,
@@ -44,20 +42,37 @@ def test_field_choice_errors_are_actionable():
         _small(batch_size=0)
 
 
-def test_pallas_kernel_requires_device_backend():
-    with pytest.raises(SpecError, match="replay.backend='device'"):
-        _small(replay_backend="host", replay_kernel="pallas")
-    # valid on the device backend
-    _small(replay_backend="device", replay_kernel="pallas")
+_REMOVED_PATHS = [("loop", "python", "execution.loop"),
+                  ("replay_backend", "host", "replay.backend")]
+
+
+@pytest.mark.parametrize("key,value,field", _REMOVED_PATHS)
+def test_removed_paths_are_rejected(key, value, field):
+    """The per-step python loop and the host replay are gone: asking for
+    either names the field and what replaced it."""
+    with pytest.raises(SpecError, match=field) as e:
+        _small(**{key: value})
+    assert "scan driver over the device replay" in str(e.value)
+
+
+@pytest.mark.parametrize("key,value,field", _REMOVED_PATHS)
+def test_restore_rejects_a_removed_path_in_checkpoint_metadata(
+        key, value, field, tmp_path):
+    """A checkpoint whose spec asks for a removed path fails on restore
+    with the same SpecError, not deep inside the run."""
+    d = _small().to_dict()
+    section, name = field.split(".")
+    d[section][name] = value
+    path = str(tmp_path / "old.npz")
+    ckpt.save(path, {"x": jnp.zeros(2)}, metadata={"spec": d})
+    with pytest.raises(SpecError, match=field):
+        Experiment.restore(path)
 
 
 def test_mesh_requires_device_backend_and_divisibility():
-    with pytest.raises(SpecError, match="mesh"):
-        _small(mesh_shards=2, replay_backend="host")
     with pytest.raises(SpecError, match="divide"):
-        _small(mesh_shards=3, replay_backend="device")  # 3 ∤ n_actors=4
-    with pytest.warns(SpecWarning, match="python"):
-        _small(mesh_shards=2, replay_backend="device", loop="python")
+        _small(mesh_shards=3)                   # 3 ∤ n_actors=4
+    assert _small(mesh_shards=2).execution.mesh_shards == 2
 
 
 def test_fused_blocks_reject_ofenet_batch_norm():
@@ -68,14 +83,8 @@ def test_fused_blocks_reject_ofenet_batch_norm():
     _small(use_ofenet=True, block_backend="fused")
 
 
-def test_runconfig_surface_removed():
-    """The deprecation period ended: both legacy names raise with a porting
-    recipe pointing at the spec aliases, and the Trainer consumes specs
-    natively (no flat view in between)."""
-    with pytest.raises(RuntimeError, match="override"):
-        RunConfig(replay_backend="host", total_steps=1)
-    with pytest.raises(RuntimeError, match="ExperimentSpec"):
-        run_training(None)
+def test_trainer_consumes_the_spec_natively():
+    """No flat config view sits between the spec and the Trainer."""
     assert not hasattr(ExperimentSpec(), "to_run_config")
     assert Trainer(presets.get("smoke")).spec is presets.get("smoke")
 
@@ -83,10 +92,9 @@ def test_runconfig_surface_removed():
 # ------------------------------------------------------------- serialization
 
 def test_dict_round_trip_and_override():
-    spec = _small(algo="td3", replay_backend="device", n_step=3,
-                  loop="scan", **{"network.connectivity": "d2rl"})
+    spec = _small(algo="td3", n_step=3, **{"network.connectivity": "d2rl"})
     assert ExperimentSpec.from_dict(spec.to_dict()) == spec
-    # dotted path and legacy alias hit the same field
+    # dotted path and flat alias hit the same field
     assert (spec.override(**{"network.num_units": 64})
             == spec.override(num_units=64))
     assert spec.override(num_units=64).network.num_units == 64
@@ -117,8 +125,7 @@ def test_ckpt_metadata_round_trip():
     """spec -> ckpt.save(metadata=...) -> load_metadata -> from_dict parity
     (the Experiment.save/restore self-description contract)."""
     import tempfile, os
-    spec = _small(algo="td3", replay_backend="device",
-                  replay_kernel="pallas", loop="scan", n_step=3)
+    spec = _small(algo="td3", replay_kernel="pallas", n_step=3)
     path = os.path.join(tempfile.mkdtemp(), "ck.npz")
     ckpt.save(path, {"x": jnp.arange(3.0)}, metadata=spec.to_dict())
     meta = ckpt.load_metadata(path)
@@ -176,21 +183,10 @@ def _final_params(exp):
 
 
 def _assert_replay_state_equal(a, b):
-    """Bitwise replay-state equality: the device ReplayState pytree, or the
-    host buffer's arrays + float64 sum tree + cursor + NumPy RNG state."""
+    """Bitwise replay-state equality over the ReplayState pytree."""
     for x, y in zip(jax.tree_util.tree_leaves(a._ls.replay),
                     jax.tree_util.tree_leaves(b._ls.replay)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-    if a.trainer.buffer is not None:
-        ia = getattr(a.trainer.buffer, "_inner", a.trainer.buffer)
-        ib = getattr(b.trainer.buffer, "_inner", b.trainer.buffer)
-        for k in ia.data:
-            np.testing.assert_array_equal(ia.data[k], ib.data[k], err_msg=k)
-        np.testing.assert_array_equal(ia.tree.tree, ib.tree.tree)
-        assert (ia.ptr, ia.count, ia.max_priority) == \
-            (ib.ptr, ib.count, ib.max_priority)
-        assert (a.trainer.rng.bit_generator.state
-                == b.trainer.rng.bit_generator.state)
 
 
 def _assert_bitwise_resume(spec, split, total, tmp_path):
@@ -217,38 +213,34 @@ def _assert_bitwise_resume(spec, split, total, tmp_path):
     return r_res
 
 
-@pytest.mark.parametrize("backend,loop", [("host", "python"),
-                                          ("host", "scan"),
-                                          ("device", "python"),
-                                          ("device", "scan")])
-def test_save_restore_resume_parity(backend, loop, tmp_path):
+_KERNEL_NSTEP = [("xla", 1), ("xla", 3), ("pallas", 1), ("pallas", 3)]
+
+
+@pytest.mark.parametrize("kernel,n_step", _KERNEL_NSTEP)
+def test_save_restore_resume_parity(kernel, n_step, tmp_path):
     """run(6); save; restore; run(6) bitwise-matches an uninterrupted
-    run(12) — the chunk-boundary split, for both loop drivers and both
-    replay backends."""
-    spec = _small(replay_backend=backend, loop=loop)
+    run(12) — the chunk-boundary split, for both sum-tree kernels, with
+    and without n-step returns."""
+    spec = _small(replay_kernel=kernel, n_step=n_step)
     r = _assert_bitwise_resume(spec, split=6, total=12, tmp_path=tmp_path)
     assert r.eval_steps == [3, 6, 9, 12]
 
 
-@pytest.mark.parametrize("backend,loop", [("host", "python"),
-                                          ("host", "scan"),
-                                          ("device", "python"),
-                                          ("device", "scan")])
-def test_resume_parity_mid_period_split(backend, loop, tmp_path):
+@pytest.mark.parametrize("kernel,n_step", _KERNEL_NSTEP)
+def test_resume_parity_mid_period_split(kernel, n_step, tmp_path):
     """The resume-ANYWHERE guarantee: a split in the middle of an eval
-    period is bitwise too. Under the scan driver this re-chunks the step
-    sequence (12 = 3+2 | 1+3+3 vs 3+3+3+3), which is only bitwise because
-    the chunk is ONE lax.scan with carried outputs — the superstep compiles
-    identically for every chunk length (no trailing unrolled superstep),
-    and save drains in-flight host io_callbacks before snapshotting."""
-    spec = _small(replay_backend=backend, loop=loop)
+    period is bitwise too. This re-chunks the step sequence (12 = 3+2 |
+    1+3+3 vs 3+3+3+3), which is only bitwise because the chunk is ONE
+    lax.scan with carried outputs — the superstep compiles identically for
+    every chunk length (no trailing unrolled superstep)."""
+    spec = _small(replay_kernel=kernel, n_step=n_step)
     _assert_bitwise_resume(spec, split=5, total=12, tmp_path=tmp_path)
 
 
 def test_resume_parity_mid_period_split_nstep(tmp_path):
     """n-step returns ride the checkpoint bitwise at a mid-period split
     (the rollback ring is part of the saved TrainLoopState)."""
-    spec = _small(replay_backend="device", loop="scan", n_step=3)
+    spec = _small(n_step=3)
     _assert_bitwise_resume(spec, split=7, total=12, tmp_path=tmp_path)
 
 
@@ -264,8 +256,7 @@ ckpt_path = os.environ["MESH_RESUME_CKPT"]
 spec = ExperimentSpec().override(
     num_units=16, num_layers=1, use_ofenet=False, distributed=True,
     n_core=1, n_env=8, total_steps=10, warmup_steps=16, eval_every=5,
-    eval_episodes=1, replay_capacity=512, batch_size=16,
-    replay_backend="device", loop="scan", mesh_shards=4)
+    eval_episodes=1, replay_capacity=512, batch_size=16, mesh_shards=4)
 full = Experiment.from_spec(spec)
 r_full = full.run(10)
 part = Experiment.from_spec(spec)
@@ -298,22 +289,8 @@ def test_resume_parity_mesh_mid_period_split(tmp_path):
     assert "OK" in r.stdout
 
 
-def test_host_backend_omits_staleness_metrics():
-    """The host buffer does not stamp add steps; its metrics must omit the
-    staleness keys rather than report a bogus -1 sentinel (the device
-    backend keeps reporting real values)."""
-    for loop in ("python", "scan"):
-        r_h = Experiment.from_spec(_small(replay_backend="host",
-                                          loop=loop)).run(6)
-        assert not any(k.startswith("staleness") for k in r_h.metrics)
-    r_d = Experiment.from_spec(_small(replay_backend="device",
-                                      loop="scan")).run(6)
-    assert r_d.metrics["staleness_mean"] >= 0.0
-    assert r_d.metrics["staleness_p50"] <= r_d.metrics["staleness_max"]
-
-
 def test_restore_preserves_eval_history_and_metrics_rows(tmp_path):
-    spec = _small(loop="scan")
+    spec = _small()
     exp = Experiment.from_spec(spec)
     exp.run(6)
     path = str(tmp_path / "ck.npz")

@@ -24,8 +24,7 @@ from repro.rl import Experiment, ExperimentSpec, Fleet, SpecError
 
 _SMALL = dict(num_units=16, num_layers=1, use_ofenet=False, n_core=1,
               n_env=4, total_steps=12, warmup_steps=8, eval_every=3,
-              eval_episodes=1, replay_capacity=256, batch_size=16,
-              replay_backend="device", loop="scan")
+              eval_episodes=1, replay_capacity=256, batch_size=16)
 
 
 def _small(**overrides):

@@ -1,8 +1,8 @@
 """Observability tests: sinks round-trip rows in order (incl. the async
 BufferedWriter, whose errors surface at drain), the stream layer filters on
-ABSOLUTE steps so both loop drivers emit the identical row set, enabling the
+ABSOLUTE steps so every chunking emits the identical row set, enabling the
 obs stream changes training outputs bitwise NOT AT ALL, resume stays bitwise
-with a JSONL sink attached (both drivers x both replay backends), and the
+with a JSONL sink attached (both sum-tree kernels x n-step 1 and 3), and the
 run-report CLI summarizes a real run directory and flags instabilities."""
 import json
 import math
@@ -171,10 +171,10 @@ def test_stream_downsamples_on_absolute_steps():
     assert run_chunks([15]) == expect
     assert run_chunks([7, 15]) == expect                 # mid-period split
     assert run_chunks([3, 6, 9, 12, 15]) == expect
-    # the python driver's per-step path produces the identical row set
+    # one-step chunks (per-step dispatch) produce the identical row set
     obs = ObsRun(ObsSpec(enabled=True, log_every=5, sinks=("memory",)))
     for s in range(1, 16):
-        obs.log_train(s, {"loss": float(s)})
+        obs.flush_chunk(s - 1, {"loss": np.array([float(s)])})
     obs.drain()
     assert [(r["step"], r["loss"]) for r in obs.rows] == expect
 
@@ -182,7 +182,7 @@ def test_stream_downsamples_on_absolute_steps():
 def test_obsrun_disabled_is_inert():
     obs = ObsRun(ObsSpec())
     obs.flush_chunk(0, {"loss": np.ones(8)})
-    obs.log_train(1, {"loss": 1.0})
+    obs.flush_chunk(8, {"loss": np.ones(1)})
     obs.log_eval(1, -10.0, {})
     obs.log_event("chunk", step=1, steps=1)
     obs.drain()
@@ -236,8 +236,8 @@ def test_scan_driver_spans_its_blocking_host_steps(tmp_path, monkeypatch):
         names.append(name)
         return real(name)
     monkeypatch.setattr(experiment, "annotate", record)
-    spec = _small(loop="scan", replay_backend="device", **_obs(
-        tmp_path, sinks=("memory",)), **{"guard.enabled": True})
+    spec = _small(**_obs(tmp_path, sinks=("memory",)),
+                  **{"guard.enabled": True})
     Experiment.from_spec(spec).run(6)
     # two chunks, each ending on an eval point (eval_every=3)
     assert names == 2 * ["repro.chunk_dispatch", "repro.obs.flush",
@@ -247,19 +247,19 @@ def test_scan_driver_spans_its_blocking_host_steps(tmp_path, monkeypatch):
 
 # ------------------------------------------------------- bitwise on/off
 
-@pytest.mark.parametrize("backend,loop", [("host", "python"),
-                                          ("host", "scan"),
-                                          ("device", "python"),
-                                          ("device", "scan")])
-def test_obs_stream_is_bitwise_invisible(backend, loop, tmp_path):
+_KERNEL_NSTEP = [("xla", 1), ("xla", 3), ("pallas", 1), ("pallas", 3)]
+
+
+@pytest.mark.parametrize("kernel,n_step", _KERNEL_NSTEP)
+def test_obs_stream_is_bitwise_invisible(kernel, n_step, tmp_path):
     """Enabling the default stream (grad-norm taps on, per-step cadence,
     jsonl+memory sinks) changes NOTHING trained: eval returns, final params
     and last sampled priorities are bitwise-identical to the obs-off run."""
-    base = dict(_SMALL, replay_backend=backend, loop=loop)
+    base = dict(_SMALL, replay_kernel=kernel, n_step=n_step)
     r_off = Experiment.from_spec(ExperimentSpec().override(**base)) \
         .run(eval_at_end=True, keep_last=True)
     exp = Experiment.from_spec(ExperimentSpec().override(
-        **base, **_obs(tmp_path / f"{backend}_{loop}")))
+        **base, **_obs(tmp_path / f"{kernel}_{n_step}")))
     r_on = exp.run(eval_at_end=True, keep_last=True)
     assert r_on.returns == r_off.returns
     assert r_on.eval_steps == r_off.eval_steps
@@ -277,16 +277,13 @@ def test_obs_stream_is_bitwise_invisible(backend, loop, tmp_path):
 
 # ------------------------------------------------------- resume parity
 
-@pytest.mark.parametrize("backend,loop", [("host", "python"),
-                                          ("host", "scan"),
-                                          ("device", "python"),
-                                          ("device", "scan")])
-def test_resume_parity_with_jsonl_sink(backend, loop, tmp_path):
+@pytest.mark.parametrize("kernel,n_step", _KERNEL_NSTEP)
+def test_resume_parity_with_jsonl_sink(kernel, n_step, tmp_path):
     """Bitwise resume at a mid-period split with the full obs stack attached
     (jsonl+memory sinks, per-step cadence): sink io never perturbs the PR-5
     contract, and the appended metrics.jsonl still reads back as one
     consistent run (dedup last-wins over the replayed steps)."""
-    spec = _small(replay_backend=backend, loop=loop,
+    spec = _small(replay_kernel=kernel, n_step=n_step,
                   **_obs(tmp_path / "run"))
     full = Experiment.from_spec(spec)
     r_full = full.run(12)
@@ -320,8 +317,7 @@ def test_resume_parity_with_jsonl_sink(backend, loop, tmp_path):
 def test_report_on_real_run_dir(tmp_path, capsys):
     """End-to-end: scan run with jsonl sink -> load_rows/summarize -> the
     summary carries throughput, grad norms and eval; the CLI renders it."""
-    spec = _small(loop="scan", replay_backend="device", srank_every=6,
-                  **_obs(tmp_path, log_every=2))
+    spec = _small(srank_every=6, **_obs(tmp_path, log_every=2))
     exp = Experiment.from_spec(spec)
     exp.run(12, eval_at_end=True)
     exp.close()
@@ -337,7 +333,7 @@ def test_report_on_real_run_dir(tmp_path, capsys):
     assert {"update_ratio_actor",
             "update_ratio_critics"} <= set(s["update_ratios"])
     assert "critic_loss" in s["losses"] and "td_error" in s["losses"]
-    assert s["staleness"]                                 # device backend
+    assert s["staleness"]
     assert s["srank"] is not None and s["srank"]["n"] == 2
     assert s["eval"]["n"] >= 4 and s["eval"]["best_return"] is not None
 

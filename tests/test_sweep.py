@@ -1,25 +1,22 @@
 """Vmapped fleet driver tests: member-vs-solo seed parity within the
-documented tolerance (device replay x scan loop — the fleet-supported
-cell), early-stop masking that freezes a member bitwise without perturbing
-its neighbors, fleet save -> restore -> run resume parity at a mid-chunk
-split, fused-vs-chunked dispatch equivalence, grid partitioning by
-compiled shape, actionable SpecErrors for unsupported configs, per-member
-obs stream demux, and PBT exploit/explore truncation selection."""
+documented tolerance, early-stop masking that freezes a member bitwise
+without perturbing its neighbors, fleet save -> restore -> run resume
+parity at a mid-chunk split, fused-vs-chunked dispatch equivalence, grid
+partitioning by compiled shape, actionable SpecErrors for unsupported
+configs, per-member obs stream demux, and PBT exploit/explore truncation
+selection."""
 import json
-import warnings
 
 import jax
 import numpy as np
 import pytest
 
-from repro.rl import (Experiment, ExperimentSpec, Fleet, SpecError,
-                      SpecWarning, Sweep)
+from repro.rl import Experiment, ExperimentSpec, Fleet, SpecError, Sweep
 from repro.rl.sweep import SOLO_PARITY_ATOL, SOLO_PARITY_RTOL
 
 _SMALL = dict(num_units=16, num_layers=1, use_ofenet=False, n_core=1,
               n_env=4, total_steps=12, warmup_steps=8, eval_every=3,
-              eval_episodes=1, replay_capacity=256, batch_size=16,
-              replay_backend="device", loop="scan")
+              eval_episodes=1, replay_capacity=256, batch_size=16)
 
 
 def _small(**overrides):
@@ -120,12 +117,6 @@ def test_fused_and_chunked_dispatch_agree_bitwise():
 
 # ------------------------------------------------------------- validation
 
-def test_host_backend_fleet_is_rejected():
-    with pytest.raises(SpecError, match="replay.backend"):
-        Fleet([_small(replay_backend="host", loop="python",
-                      distributed=True)])
-
-
 def test_pallas_kernel_fleet_is_rejected():
     with pytest.raises(SpecError, match="kernel"):
         Fleet([_small(replay_kernel="pallas")])
@@ -147,13 +138,6 @@ def test_from_grid_partitions_by_compiled_shape():
     assert [r.point["num_units"] for r in res] == [16, 16, 24, 24]
     assert [r.seed for r in res] == [0, 1, 0, 1]
     assert all(len(r.result.returns) == 2 for r in res)
-
-
-def test_from_grid_upgrades_host_spec_with_warning():
-    base = _small(replay_backend="host", loop="python", distributed=True)
-    with pytest.warns(SpecWarning, match="device"):
-        sweep = Sweep.from_grid(base, seeds=2)
-    assert sweep.fleets[0].spec.replay.backend == "device"
 
 
 # --------------------------------------------------------------- obs demux

@@ -1,9 +1,9 @@
-"""Scan-superstep training loop tests: seed-for-seed parity between
-``execution.loop="scan"`` and the per-step Python loop for BOTH replay
-backends, the host-dispatch bound, n-step return emission against a NumPy
-reference, the priority-staleness metric, the jitted eval rollout, the
-superstep's phase scopes, and the 4-fake-device mesh-sharded runner
-(subprocess, like test_substrate)."""
+"""Scan-superstep training loop tests: bitwise parity between one long chunk
+and the same steps re-chunked one per dispatch, for both sum-tree kernels,
+the host-dispatch bound, n-step return emission against a NumPy reference,
+the priority-staleness metric, the jitted eval rollout, the superstep's
+phase scopes, and the 4-fake-device mesh-sharded runner (subprocess, like
+test_substrate)."""
 import contextlib
 import os
 import re
@@ -31,36 +31,55 @@ def _run(**overrides):
     return Experiment.from_spec(spec).run(eval_at_end=True, keep_last=True)
 
 
-# ------------------------------------------------------- scan/python parity
+# ------------------------------------------------------------- re-chunking
 
-@pytest.mark.parametrize("backend,n_step", [("device", 1), ("device", 3),
-                                            ("host", 1), ("host", 3)])
-def test_scan_matches_python_loop(backend, n_step):
-    """Same spec => identical returns and final priorities across loop
-    drivers, for the device replay and the host (io_callback) replay."""
-    cfg = dict(_BASE, replay_backend=backend, n_step=n_step)
-    r_py = _run(**cfg, loop="python")
-    r_sc = _run(**cfg, loop="scan")
-    np.testing.assert_allclose(r_sc.returns, r_py.returns, rtol=1e-4)
-    np.testing.assert_allclose(r_sc.last_priorities, r_py.last_priorities,
-                               rtol=1e-3, atol=1e-5)
-    assert r_sc.eval_steps == r_py.eval_steps == [6, 12]
-    # the traced-call counter: scan dispatches one chunk per eval point
-    # (+ O(1) warmup/init), the python loop ~5 programs per gradient step
+def _run_in(pieces, **overrides):
+    """``run`` once per entry of ``pieces`` on one handle; the last call
+    keeps the final batch/priorities and the agent state."""
+    exp = Experiment.from_spec(ExperimentSpec().override(**overrides))
+    for n in pieces[:-1]:
+        exp.run(n)
+    return exp.run(pieces[-1], keep_last=True)
+
+
+def _assert_bitwise(a, b):
+    assert a.returns == b.returns
+    assert a.eval_steps == b.eval_steps
+    np.testing.assert_array_equal(a.last_priorities, b.last_priorities)
+    for x, y in zip(jax.tree_util.tree_leaves(a.state["params"]),
+                    jax.tree_util.tree_leaves(b.state["params"])):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("kernel,n_step", [("xla", 1), ("xla", 3),
+                                           ("pallas", 1), ("pallas", 3)])
+def test_rechunked_run_is_bitwise(kernel, n_step):
+    """``run(12)`` and twelve ``run(1)`` calls (the per-step dispatch shape
+    through the scan body) give bitwise-equal returns, final params and
+    last priorities, for both sum-tree kernels, with and without n-step."""
+    cfg = dict(_BASE, replay_kernel=kernel, n_step=n_step)
+    r_one = _run_in([12], **cfg)
+    r_steps = _run_in([1] * 12, **cfg)
+    _assert_bitwise(r_one, r_steps)
+    assert r_one.eval_steps == [6, 12]
+    # the traced-call counter: one chunk per eval point (+ O(1)
+    # warmup/init); per-step calls dispatch one chunk per step
     budget = _BASE["total_steps"] / _BASE["eval_every"] + 8
-    assert r_sc.metrics["host_dispatches"] <= budget, r_sc.metrics
-    assert r_py.metrics["host_dispatches"] > r_sc.metrics["host_dispatches"]
+    assert r_one.metrics["host_dispatches"] <= budget, r_one.metrics
+    assert (r_steps.metrics["host_dispatches"]
+            > r_one.metrics["host_dispatches"])
 
 
-def test_scan_matches_python_loop_sranks():
-    """srank instrumentation points must agree across loop drivers even when
-    srank_every does not divide eval_every (scan chunks stop at both)."""
-    cfg = dict(_BASE, replay_backend="device", srank_every=4)
-    r_py = _run(**cfg, loop="python")
-    r_sc = _run(**cfg, loop="scan")
-    assert len(r_py.sranks) == len(r_sc.sranks) == 3
-    assert r_py.sranks == r_sc.sranks
-    np.testing.assert_allclose(r_sc.returns, r_py.returns, rtol=1e-4)
+def test_rechunked_run_is_bitwise_sranks():
+    """srank points land on the same absolute steps however the run is
+    split, even when srank_every does not divide eval_every (chunks stop
+    at both)."""
+    cfg = dict(_BASE, srank_every=4)
+    r_one = _run_in([12], **cfg)
+    r_split = _run_in([5, 7], **cfg)
+    assert len(r_one.sranks) == 3
+    assert r_one.sranks == r_split.sranks
+    _assert_bitwise(r_one, r_split)
 
 
 def test_scan_superstep_fused_block_backend_matches_jnp(monkeypatch):
@@ -76,8 +95,7 @@ def test_scan_superstep_fused_block_backend_matches_jnp(monkeypatch):
         return inner(*a, **k)
     monkeypatch.setattr(stack_mod, "dense_stack", counted)
 
-    cfg = dict(_BASE, replay_backend="device", use_ofenet=True,
-               ofenet_layers=2, ofenet_units=16, loop="scan")
+    cfg = dict(_BASE, use_ofenet=True, ofenet_layers=2, ofenet_units=16)
     r_jnp = _run(**cfg, block_backend="jnp")
     assert calls["n"] == 0                     # jnp backend never routes here
     r_fused = _run(**cfg, block_backend="fused")
@@ -86,15 +104,6 @@ def test_scan_superstep_fused_block_backend_matches_jnp(monkeypatch):
     np.testing.assert_allclose(r_fused.last_priorities, r_jnp.last_priorities,
                                rtol=5e-3, atol=1e-4)
     assert r_fused.eval_steps == r_jnp.eval_steps
-
-
-def test_scan_matches_python_loop_pallas_kernel():
-    """Loop driver parity must hold through the Pallas sum-tree too."""
-    cfg = dict(_BASE, total_steps=6, eval_every=6, replay_capacity=128,
-               replay_backend="device", replay_kernel="pallas")
-    r_py = _run(**cfg, loop="python")
-    r_sc = _run(**cfg, loop="scan")
-    np.testing.assert_allclose(r_sc.returns, r_py.returns, rtol=1e-4)
 
 
 # ----------------------------------------------------------- n-step returns
@@ -149,23 +158,19 @@ def test_nstep_emission_matches_numpy_reference():
 
 def test_nstep_one_is_identity_semantics():
     """n_step=1 keeps the legacy transition schema (no disc column)."""
-    res = _run(**dict(_BASE, total_steps=4, eval_every=4,
-                      replay_backend="device", n_step=1))
+    res = _run(**dict(_BASE, total_steps=4, eval_every=4, n_step=1))
     assert "disc" not in res.last_batch
 
 
 # ------------------------------------------------------- staleness metric
 
 def test_staleness_metric_tracks_add_age():
-    cfg = dict(_BASE, replay_backend="device", total_steps=30, eval_every=30)
-    res = _run(**cfg, loop="scan")
+    cfg = dict(_BASE, total_steps=30, eval_every=30)
+    res = _run(**cfg)
     # sampled rows were added between warmup (step 0) and the last step
     assert 0.0 <= res.metrics["staleness_mean"] <= cfg["total_steps"]
     assert res.metrics["staleness_p50"] <= res.metrics["staleness_max"]
     assert res.metrics["staleness_max"] <= cfg["total_steps"]
-    # host buffer does not stamp rows: staleness keys omitted (no sentinel)
-    res_h = _run(**dict(cfg, replay_backend="host"))
-    assert not any(k.startswith("staleness") for k in res_h.metrics)
 
 
 # ------------------------------------------------------------ jitted eval
@@ -195,26 +200,26 @@ PHASES = ("repro.collect", "repro.replay.add", "repro.replay.sample",
           "repro.update", "repro.replay.refresh")
 
 
-def _lowered_chunk(backend):
+def _lowered_chunk(kernel):
     exp = Experiment.from_spec(ExperimentSpec().override(
-        **_BASE, replay_backend=backend, loop="scan"))
+        **_BASE, replay_kernel=kernel))
     exp._ensure_init()
     return exp.trainer.chunk_fn(3, False).__wrapped__.lower(exp._ls)
 
 
-@pytest.mark.parametrize("backend", ["device", "host"])
-def test_chunk_names_each_phase_and_adds_no_instruction(backend,
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_chunk_names_each_phase_and_adds_no_instruction(kernel,
                                                         monkeypatch):
     """Each superstep phase runs under its own ``jax.named_scope``, which
     reaches the ops' metadata (the profiler's ``tf_op``) and nothing else:
     lowered again with the scopes turned off, the chunk is the same
     program once the printer leaves the locations out."""
-    scoped = _lowered_chunk(backend)
+    scoped = _lowered_chunk(kernel)
     names = set(re.findall(r"repro\.[a-z.]+", scoped.as_text(debug_info=True)))
     assert set(PHASES) <= names
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    plain = _lowered_chunk(backend)
+    plain = _lowered_chunk(kernel)
     assert not set(PHASES) & set(re.findall(
         r"repro\.[a-z.]+", plain.as_text(debug_info=True)))
     assert plain.as_text() == scoped.as_text()
@@ -248,26 +253,26 @@ def run(**kw):
 base = dict(env="pendulum", algo="sac", num_units=16, num_layers=1,
             use_ofenet=False, distributed=True, n_core=1, n_env=8,
             total_steps=10, warmup_steps=16, eval_every=5, eval_episodes=2,
-            replay_capacity=512, batch_size=16, replay_backend="device")
-single = run(**base, loop="scan")
+            replay_capacity=512, batch_size=16)
+single = run(**base)
 assert calls["collect_and_add_sharded"] == 0      # single shard: direct path
-r_scan = run(**base, loop="scan", mesh_shards=4)
+r_scan = run(**base, mesh_shards=4)
 assert calls["collect_and_add_sharded"] > 0, calls
 assert calls["sharded_replay_sample"] > 0, calls
 assert r_scan.metrics["host_dispatches"] <= 10, r_scan.metrics
 assert r_scan.metrics["staleness_mean"] >= 0
-import warnings
-with warnings.catch_warnings():
-    warnings.simplefilter("ignore")               # python loop on a mesh
-    r_py = run(**base, loop="python", mesh_shards=4)
-np.testing.assert_allclose(r_scan.returns, r_py.returns, rtol=1e-4)
+# re-chunked on the mesh: bitwise the same run
+split = Experiment.from_spec(ExperimentSpec().override(**base, mesh_shards=4))
+split.run(3)
+r_split = split.run(7, eval_at_end=True)
+assert r_split.returns == r_scan.returns, (r_split.returns, r_scan.returns)
 assert np.isfinite(r_scan.returns).all()
 # same env/budget/seed: the sharded learning curve stays in the same
 # ballpark as single-shard (pendulum random policy scores ~-1200)
 assert abs(np.mean(r_scan.returns) - np.mean(single.returns)) < 400, (
     r_scan.returns, single.returns)
 # n-step rides the sharded ring too
-r_n3 = run(**base, loop="scan", mesh_shards=4, n_step=3)
+r_n3 = run(**base, mesh_shards=4, n_step=3)
 assert np.isfinite(r_n3.returns).all()
 print("OK")
 """
@@ -293,8 +298,7 @@ from repro.rl.runner import Trainer
 
 spec = ExperimentSpec().override(
     num_units=16, num_layers=1, use_ofenet=False, n_core=1, n_env=8,
-    batch_size=16, replay_capacity=512, warmup_steps=16,
-    replay_backend="device", loop="scan", mesh_shards=4)
+    batch_size=16, replay_capacity=512, warmup_steps=16, mesh_shards=4)
 tr = Trainer(spec)
 ls, metrics, _ = jax.eval_shape(tr._superstep, tr.init_template())
 assert metrics["staleness_p50"].shape == ()
